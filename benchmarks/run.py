@@ -46,6 +46,8 @@ def main() -> None:
                     help="write one BENCH_<section>.json artifact per "
                          "section into this directory")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     header()
     from benchmarks import (checkpoint_bench, graphdiff_bench, kernel_bench,

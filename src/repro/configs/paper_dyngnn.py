@@ -8,6 +8,11 @@ run on CPU.  Shapes: one `dtdg_train` cell per dataset scale.
 from repro.configs.registry import ArchSpec, ShapeSpec, register
 from repro.core.models import DynGNNConfig
 
+FULL_SHAPE = "dtdg_epinions"    # what --full-config trains
+CHURN = 0.1                     # synthetic traces: edges resampled per step
+# host-side smoothing (§5.4) each model trains on
+SMOOTHING = {"tmgcn": "mproduct", "evolvegcn": "edgelife", "cdgcn": "none"}
+
 _DATASETS = {
     # name: (N, T, smoothed edges per snapshot).  N and T are rounded from
     # Table 1 to multiples of 32 resp. 128 so the production meshes divide
@@ -29,10 +34,28 @@ def _shapes():
     }
 
 
+def synthetic_trace(cfg: DynGNNConfig, num_steps: int | None = None):
+    """The ``FULL_SHAPE`` trace for ``cfg``'s model, seeded: the shape's N
+    and smoothed edges per snapshot, and its T unless ``num_steps`` cuts
+    it.  ``evolving_dynamic_graph`` keeps 1 - CHURN of each snapshot, so
+    a union of ``window`` consecutive snapshots (M-transform, edge-life)
+    holds about 1 + (window - 1) * CHURN raw snapshots' worth of edges."""
+    from repro.run.data import SyntheticTrace
+    d = _shapes()[FULL_SHAPE].dims
+    smooth = SMOOTHING[cfg.model]
+    union = 1.0 if smooth == "none" else 1 + (cfg.window - 1) * CHURN
+    return SyntheticTrace(
+        num_nodes=d["n_nodes"], num_steps=num_steps or d["n_steps"],
+        density=d["edges_per_snap"] / (d["n_nodes"] * union), churn=CHURN,
+        smoothing_mode=smooth, window=cfg.window)
+
+
 def _mk(model: str):
     def make_config():
-        return DynGNNConfig(model=model, feat_in=2, hidden=6, out_dim=6,
-                            num_layers=2, window=5, num_classes=2,
+        full = _shapes()[FULL_SHAPE].dims
+        return DynGNNConfig(model=model, num_nodes=full["n_nodes"],
+                            num_steps=full["n_steps"], feat_in=2, hidden=6,
+                            out_dim=6, num_layers=2, window=5, num_classes=2,
                             checkpoint_blocks=4)
 
     def make_smoke_config():

@@ -52,7 +52,8 @@ def register(spec: ArchSpec) -> ArchSpec:
 
 
 def get_arch(arch_id: str) -> ArchSpec:
-    if not _REGISTRY:
+    # importing one config module registers only its own archs
+    if arch_id not in _REGISTRY:
         load_all()
     if arch_id not in _REGISTRY:
         raise KeyError(f"unknown arch '{arch_id}'; have "
@@ -61,8 +62,7 @@ def get_arch(arch_id: str) -> ArchSpec:
 
 
 def all_archs() -> dict[str, ArchSpec]:
-    if not _REGISTRY:
-        load_all()
+    load_all()
     return dict(_REGISTRY)
 
 

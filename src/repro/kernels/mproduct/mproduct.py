@@ -44,7 +44,10 @@ def _kernel(toff_ref, x_prev_ref, x_cur_ref, out_ref, *, window: int,
     in_band = (k <= g) & (k > g - window) & (k >= 1)
     denom = jnp.maximum(jnp.minimum(window, g), 1).astype(x.dtype)
     band = jnp.where(in_band, 1.0, 0.0).astype(x.dtype) / denom
+    # HIGHEST: the band weights (1/min(w, t)) and x must not be rounded to
+    # bf16 on the MXU — the op is the f32 M-product of the dense oracle
     out_ref[...] = jax.lax.dot(band, x,
+                               precision=jax.lax.Precision.HIGHEST,
                                preferred_element_type=jnp.float32
                                ).astype(out_ref.dtype)
 

@@ -15,7 +15,7 @@ Safety properties of the bucketed layout:
   destination distribution would silently drop overflow edges; the wrapper
   now counts weighted overflow lanes and surfaces the count through
   ``checkify.debug_check`` (wrap the jitted caller in
-  ``checkify.checkify(..., errors=checkify.all_checks)`` to materialize the
+  ``checkify.checkify(..., errors=checkify.user_checks)`` to materialize the
   error).  ``segment_spmm_checked`` is the documented dense-fallback path:
   it prechecks the bucket layout on host and reroutes overflowing calls to
   the XLA segment-sum oracle instead of returning a wrong answer.

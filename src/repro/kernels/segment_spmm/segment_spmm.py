@@ -19,6 +19,14 @@ Grid: (NB, F / F_BLK); each step computes
 
 an (N_BLK x EPB) @ (EPB x F_BLK) MXU matmul.  VMEM working set:
 EPB*F_BLK + EPB*N_BLK + N_BLK*F_BLK floats — all tile-aligned (128 lanes).
+
+``dst_local`` enters the kernel as (NB, 1, EPB) with a (1, 1, EPB) block:
+Mosaic requires a block's last two dims to be (8, 128)-divisible or equal
+to the array's, and a (1, EPB) block of the 2-D array is neither.  The
+one-hot is built directly in its transposed (N_BLK, EPB) form, with the
+destination row broadcast along sublanes, so the contraction is a plain
+matmul.  The dot runs at HIGHEST precision: one-hot weights are exact,
+and the sums must stay f32-exact like the segment-sum oracle's.
 """
 
 from __future__ import annotations
@@ -36,17 +44,15 @@ DEFAULT_FEAT_BLOCK = 128
 
 
 def _kernel(dst_ref, msg_ref, out_ref, *, node_block: int):
-    # dst_ref: (1, EPB) int32; msg_ref: (1, EPB, FB); out_ref: (1, NB, FB)
-    dst = dst_ref[0]                                   # (EPB,)
+    # dst_ref: (1, 1, EPB) int32; msg_ref: (1, EPB, FB); out_ref: (1, NB, FB)
+    dst = dst_ref[0]                                   # (1, EPB)
     msgs = msg_ref[0]                                  # (EPB, FB)
-    # One-hot over the node block; padded lanes (dst == node_block or any
-    # value >= node_block) match no column and vanish.
-    cols = jax.lax.broadcasted_iota(jnp.int32, (dst.shape[0], node_block), 1)
-    onehot = (dst[:, None] == cols).astype(msgs.dtype)  # (EPB, NB)
-    acc = jax.lax.dot_general(
-        onehot, msgs,
-        dimension_numbers=(((0,), (0,)), ((), ())),     # contract over EPB
-        preferred_element_type=jnp.float32)
+    # Transposed one-hot over the node block; padded lanes (dst ==
+    # node_block or any value >= node_block) match no row and vanish.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (node_block, dst.shape[1]), 0)
+    onehot_t = (rows == dst).astype(msgs.dtype)        # (NB, EPB)
+    acc = jnp.dot(onehot_t, msgs, precision=jax.lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
     out_ref[0] = acc.astype(out_ref.dtype)
 
 
@@ -67,11 +73,11 @@ def bucketed_segment_sum(dst_local: jax.Array, messages: jax.Array,
         functools.partial(_kernel, node_block=node_block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, epb), lambda i, _j: (i, 0)),
+            pl.BlockSpec((1, 1, epb), lambda i, _j: (i, 0, 0)),
             pl.BlockSpec((1, epb, feat_block), lambda i, j: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, node_block, feat_block),
                                lambda i, j: (i, 0, j)),
         out_shape=jax.ShapeDtypeStruct((nb, node_block, f), messages.dtype),
         interpret=interpret,
-    )(dst_local, messages)
+    )(dst_local.reshape(nb, 1, epb), messages)

@@ -9,8 +9,13 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh_compat
 from repro.dist.sharding import DATA_AXIS, MODEL_AXIS, POD_AXIS
+
+
+def _make_mesh(shape, axis_names):
+    return jax.make_mesh(shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,7 +23,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ((POD_AXIS, DATA_AXIS, MODEL_AXIS) if multi_pod
             else (DATA_AXIS, MODEL_AXIS))
-    return make_mesh_compat(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -27,7 +32,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     if data * model > n:
         raise ValueError(f"mesh {data}x{model} needs {data * model} devices, "
                          f"have {n}")
-    return make_mesh_compat((data, model), (DATA_AXIS, MODEL_AXIS))
+    return _make_mesh((data, model), (DATA_AXIS, MODEL_AXIS))
 
 
 def mesh_device_count(mesh) -> int:

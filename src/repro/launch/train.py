@@ -57,7 +57,9 @@ def main() -> None:
                     help="0 = all available devices")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--full-config", action="store_true",
-                    help="use the full (pod-scale) config instead of smoke")
+                    help="dyngnn: train on the dtdg_epinions shape "
+                         "(N=755,200, T=512, ~2.1M smoothed edges per "
+                         "snapshot) instead of the smoke config")
     ap.add_argument("--stream", action="store_true",
                     help="dyngnn only: per-snapshot streaming training "
                          "over the async graph-diff delta stream")
@@ -118,6 +120,8 @@ def main() -> None:
                          "per line); mesh runs also print the "
                          "round_time_model calibration residuals")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         from repro import obs
         obs.configure(enabled=True)
@@ -148,14 +152,17 @@ def main() -> None:
         from repro.run import (CheckpointSpec, DeviceBudgetError, Engine,
                                ExecutionPlan, RunConfig, SamplingSpec,
                                SyntheticTrace)
-        cfg = (arch.make_config() if args.full_config
-               else arch.make_smoke_config())
-        smooth = {"tmgcn": "mproduct", "evolvegcn": "edgelife",
-                  "cdgcn": "none"}[cfg.model]
-        data = SyntheticTrace(num_nodes=cfg.num_nodes,
-                              num_steps=cfg.num_steps, density=3.0,
-                              churn=0.1, smoothing_mode=smooth,
-                              window=cfg.window)
+        from repro.configs import paper_dyngnn
+        if args.full_config:
+            cfg = arch.make_config()
+            data = paper_dyngnn.synthetic_trace(cfg)
+        else:
+            cfg = arch.make_smoke_config()
+            data = SyntheticTrace(
+                num_nodes=cfg.num_nodes, num_steps=cfg.num_steps,
+                density=3.0, churn=paper_dyngnn.CHURN,
+                smoothing_mode=paper_dyngnn.SMOOTHING[cfg.model],
+                window=cfg.window)
         budget = args.device_budget or None
         if args.sampled:
             try:

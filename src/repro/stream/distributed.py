@@ -46,7 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from repro import obs
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import models as mdl
 from repro.core import partition
 from repro.dist import compression as compression_lib

@@ -196,14 +196,12 @@ class DeltaApplier:
     """
 
     def __init__(self, max_edges: int, donate: bool = True, device=None):
-        self.edges = jnp.zeros((max_edges, 2), dtype=jnp.int32)
-        self.mask = jnp.zeros((max_edges,), dtype=jnp.float32)
-        if device is not None:
-            # Pin the ring to one shard's device: with committed inputs the
-            # jitted apply (and every donation) stays on that device, so P
-            # shard rings run truly independent per-device streams.
-            self.edges = jax.device_put(self.edges, device)
-            self.mask = jax.device_put(self.mask, device)
+        # ``device`` pins the ring to one shard's device (allocated there,
+        # never staged through the default device): with committed inputs
+        # the jitted apply (and every donation) stays on that device, so P
+        # shard rings run truly independent per-device streams.
+        self.edges = jnp.zeros((max_edges, 2), dtype=jnp.int32, device=device)
+        self.mask = jnp.zeros((max_edges,), dtype=jnp.float32, device=device)
         self._apply = (sanitize.guard_donated(_APPLY_DONATING, (0, 1))
                        if donate else _APPLY_PLAIN)
         self._decode = (sanitize.guard_donated(_DECODE_DONATING, (0, 1))

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.dist import compression
 
 PARTS = 4

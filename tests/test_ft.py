@@ -247,7 +247,7 @@ def test_int8_error_feedback_compression():
     error feedback drives the residual to track the truncation."""
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_host_mesh
     mesh = make_host_mesh(data=4, model=1)
     rng = np.random.default_rng(0)

@@ -123,7 +123,7 @@ def test_hybrid_spmm_matches_dense():
     from functools import partial as fpartial
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     mesh = make_host_mesh(data=1, model=4)
     rng = np.random.default_rng(0)
     n, e, f = 64, 512, 8

@@ -92,7 +92,7 @@ def test_tight_budget_overflow_surfaces_via_checkify():
     fn = checkify.checkify(
         lambda xx, ee, ww: spmm_ops.segment_spmm(xx, ee, ww, N,
                                                  edges_per_block=128),
-        errors=checkify.all_checks)
+        errors=checkify.user_checks)
     err, _ = fn(x, edges, w)
     with pytest.raises(checkify.JaxRuntimeError,
                        match="overflow edges_per_block"):
@@ -100,7 +100,7 @@ def test_tight_budget_overflow_surfaces_via_checkify():
     # the safe default budget passes the same check
     fn_ok = checkify.checkify(
         lambda xx, ee, ww: spmm_ops.segment_spmm(xx, ee, ww, N),
-        errors=checkify.all_checks)
+        errors=checkify.user_checks)
     err_ok, out = fn_ok(x, edges, w)
     err_ok.throw()
     np.testing.assert_allclose(

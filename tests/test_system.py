@@ -112,7 +112,7 @@ def test_grad_compression_trains():
     communication path, §5.5 + compression)."""
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.dist import compression
     mesh = make_host_mesh(data=4, model=1)
     rng = np.random.default_rng(0)
