@@ -1,0 +1,10 @@
+"""Device milliseconds per snapshot under the program's
+``edge_weights`` scope (self-loops, degree counts, Laplacian weights),
+summed over the chips (``stages.py``).  Nothing to read where the trace
+holds no stage scope."""
+
+import stages
+
+
+def read(ctx):
+    return stages.per_snapshot_ms(ctx, "edge_weights")

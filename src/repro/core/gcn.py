@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graph import segment
+from repro.obs import stages
 
 Array = jax.Array
 
@@ -27,6 +28,7 @@ def init_gcn_params(key: Array, f_in: int, f_out: int,
     }
 
 
+@jax.named_scope(stages.SPMM)
 def spatial_aggregate(x: Array, edges: Array, edge_weights: Array,
                       num_nodes: int, use_pallas: bool = False,
                       interpret: bool | None = None) -> Array:

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import stages
+
 Array = jax.Array
 
 
@@ -125,6 +127,7 @@ def encode_stream(snapshots: list[np.ndarray],
     return out
 
 
+@jax.named_scope(stages.DELTA_APPLY)
 def apply_delta(prev_edges: Array, prev_mask: Array, drop_pos: Array,
                 drop_mask: Array, add_edges: Array, add_mask: Array
                 ) -> tuple[Array, Array]:

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.core import gcn as gcnlib
 from repro.core import temporal
 from repro.core.dtdg import DTDGBatch
+from repro.obs import stages
 
 Array = jax.Array
 
@@ -126,6 +127,7 @@ def init_carries(cfg: DynGNNConfig, params: dict,
 
 # ---------------------------------------------------- layer-slice steps -----
 
+@jax.named_scope(stages.SPATIAL)
 def spatial_stage(cfg: DynGNNConfig, layer_params: dict, _layer: int,
                   x: Array, edges: Array, edge_weights: Array,
                   carry: Any, _t_offset: Array | int) -> tuple[Array, Any]:
@@ -164,6 +166,7 @@ def spatial_stage(cfg: DynGNNConfig, layer_params: dict, _layer: int,
     return y, carry
 
 
+@jax.named_scope(stages.TEMPORAL)
 def temporal_stage(cfg: DynGNNConfig, layer_params: dict, _layer: int,
                    y: Array, carry: Any,
                    t_offset: Array | int) -> tuple[Array, Any]:

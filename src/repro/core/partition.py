@@ -32,6 +32,7 @@ from repro.core import models as mdl
 from repro.dist import compression as compression_lib
 from repro.core import temporal
 from repro.core.dtdg import DTDGBatch
+from repro.obs import stages
 
 Array = jax.Array
 
@@ -118,6 +119,7 @@ def _sp_block_body(cfg: mdl.DynGNNConfig, params: dict, axis,
     def _feature_cuts(width):
         return [width * c // a2a_chunks for c in range(1, a2a_chunks)]
 
+    @jax.named_scope(stages.A2A)
     def a2a(y, split_axis):
         orig = y.dtype
         if comm_dtype is not None:
@@ -134,6 +136,7 @@ def _sp_block_body(cfg: mdl.DynGNNConfig, params: dict, axis,
             y = _major_all_to_all(y, axis, num_procs, split_axis)
         return y.astype(orig)
 
+    @jax.named_scope(stages.A2A)
     def a2a_q(y, res, split_axis, concat_axis):
         # int8 redistribution with per-shard error feedback; chunking
         # slices payload AND residual with the same feature cuts so each
@@ -161,17 +164,18 @@ def _sp_block_body(cfg: mdl.DynGNNConfig, params: dict, axis,
             # every processor redundantly evolves the block's weights from the
             # carried block-boundary state (weights are tiny — §5.5), then
             # slices its own bsl steps.
-            w_prev, st = carries[l]
-            ws, w_last, st_last = temporal.evolve_weights_from(
-                lp["evolve"], w_prev, st, bsl * num_procs)
-            ws_local = jax.lax.dynamic_slice_in_dim(ws, p_idx * bsl, bsl, 0)
-
             def per_step(xt, et, wt, w_t):
                 y0 = mdl.gcnlib.spatial_aggregate(xt, et, wt, xt.shape[0],
                                                   cfg.use_pallas)
                 return jax.nn.relu(y0 @ w_t)
 
-            h = jax.vmap(per_step)(h, e_b, w_b, ws_local)
+            with jax.named_scope(stages.SPATIAL):
+                w_prev, st = carries[l]
+                ws, w_last, st_last = temporal.evolve_weights_from(
+                    lp["evolve"], w_prev, st, bsl * num_procs)
+                ws_local = jax.lax.dynamic_slice_in_dim(ws, p_idx * bsl,
+                                                        bsl, 0)
+                h = jax.vmap(per_step)(h, e_b, w_b, ws_local)
             new_carries.append((w_last, st_last))
             # EvolveGCN's temporal op acts on weights -> feature path needs
             # NO redistribution (the model is communication-free, §5.5).

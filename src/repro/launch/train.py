@@ -19,9 +19,8 @@ import jax
 import numpy as np
 
 
-def _finish_trace(path: str | None, result=None) -> None:
-    """Export the session trace (``--trace``) and, for mesh runs,
-    print the model-vs-measured calibration summary."""
+def _finish_trace(path: str | None) -> None:
+    """Export the session trace (``--trace``)."""
     if not path:
         return
     from repro import obs
@@ -29,13 +28,6 @@ def _finish_trace(path: str | None, result=None) -> None:
     out = obs.export_trace(path)
     dropped = f" ({trc.dropped} spans dropped)" if trc.dropped else ""
     print(f"trace: {len(trc.spans())} spans -> {out}{dropped}")
-    if result is not None and result.per_shard_bytes is not None:
-        # int8 wire formats quarter the a2a bytes the model predicts
-        ratio = 0.25 if result.compression != "none" else 1.0
-        rep = obs.calibration_report(
-            trc.spans(), chunks=result.a2a_chunks,
-            pipeline_rounds=result.pipeline_rounds, a2a_wire_ratio=ratio)
-        print(rep.summary())
 
 
 def _parse_rescale(spec: str) -> tuple[int, int]:
@@ -116,9 +108,8 @@ def main() -> None:
     ap.add_argument("--trace", default=None, metavar="OUT.json",
                     help="enable the repro.obs tracer and export a "
                          "Perfetto-loadable Chrome trace of the run "
-                         "(phase spans + counters; .jsonl for one event "
-                         "per line); mesh runs also print the "
-                         "round_time_model calibration residuals")
+                         "(spans + counters; .jsonl for one event per "
+                         "line)")
     args = ap.parse_args()
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -235,7 +226,7 @@ def main() -> None:
             # the budget gate refusing IS the answer the flag asks for —
             # report it as a one-line CLI outcome, not a traceback
             raise SystemExit(f"refused: {e}") from None
-        _finish_trace(args.trace, result)
+        _finish_trace(args.trace)
         rep = result.transfer_report
         if args.sampled:
             final = (f"{result.losses[-1]:.4f}" if result.losses else "n/a")

@@ -17,11 +17,11 @@ from __future__ import annotations
 
 from typing import Any
 
-from repro.obs.calibrate import (PHASES, CalibrationReport, CalibrationRow,
-                                 calibration_report, phase_durations)
 from repro.obs.export import (chrome_trace_events, export_trace, load_trace,
                               validate_trace)
 from repro.obs.metrics import REGISTRY, MetricsRegistry
+from repro.obs.stages import (A2A, DELTA_APPLY, EDGE_WEIGHTS, LOSS,
+                              OPTIMIZER, SPATIAL, SPMM, STAGES, TEMPORAL)
 from repro.obs.trace import NULL_SPAN, Span, Stopwatch, Tracer
 
 __all__ = [
@@ -31,19 +31,28 @@ __all__ = [
     "span", "stopwatch", "add_span", "now_s", "span_summary",
     "metrics", "inc", "gauge", "metrics_snapshot",
     "chrome_trace_events", "export_trace", "load_trace", "validate_trace",
-    "PHASES", "CalibrationRow", "CalibrationReport",
-    "calibration_report", "phase_durations",
+    "STAGES", "DELTA_APPLY", "EDGE_WEIGHTS", "SPATIAL", "SPMM", "TEMPORAL",
+    "A2A", "LOSS", "OPTIMIZER",
 ]
 
 _tracer = Tracer(enabled=False)
 
 
 def configure(enabled: bool = True, capacity: int = 65536,
-              fence: bool = True, phases: bool = True) -> Tracer:
-    """Install (and return) a fresh global tracer."""
+              fence: bool = True, **retired: Any) -> Tracer:
+    """Install (and return) a fresh global tracer.
+
+    ``phases=False`` is still accepted from callers written when the
+    distributed trainer could derive per-round phase spans; the
+    derivation is gone (the step's stages are named scopes now, read off
+    the device trace), so any other value of it, or any other keyword,
+    is an error."""
+    if retired and retired != {"phases": False}:
+        raise TypeError(f"configure() got unexpected keywords "
+                        f"{sorted(retired)}; derived phase spans were "
+                        "removed (see repro.obs.stages)")
     global _tracer
-    _tracer = Tracer(enabled=enabled, capacity=capacity, fence=fence,
-                     phases=phases)
+    _tracer = Tracer(enabled=enabled, capacity=capacity, fence=fence)
     return _tracer
 
 

@@ -23,8 +23,14 @@ clock.  Device work is asynchronous under jax — with ``fence=True``
 ``jax.block_until_ready`` on whatever the span registered via
 ``Span.fence(obj)``, so device phases measure *execution*, not
 dispatch.  Fencing serializes the dispatch pipeline — a traced run
-measures a serial schedule (the observer effect the calibration report
-accounts for by comparing against ``round_time_model``'s ``serial_s``).
+measures a serial schedule.
+
+An enabled tracer also mirrors every span it times (``Span`` and
+``Stopwatch``) as a ``jax.profiler.TraceAnnotation`` of the same name,
+with the span's attributes and ``cat`` as metadata: under
+``jax.profiler`` each span lands in the ``.xplane.pb`` on the
+profiler's clock, on its own thread's line, beside the device ops.  A
+disabled tracer opens none.
 """
 
 from __future__ import annotations
@@ -37,13 +43,23 @@ from typing import Any, Iterator
 __all__ = ["Span", "Stopwatch", "Tracer", "NULL_SPAN"]
 
 
+def _annotation(name: str, cat: str, attrs: dict[str, Any]):
+    """An entered ``jax.profiler.TraceAnnotation`` mirroring one span.
+    Its metadata is the span's attributes plus its category, which also
+    tells the program's spans from JAX's own events in the trace."""
+    import jax
+    ann = jax.profiler.TraceAnnotation(name, cat=cat, **attrs)
+    ann.__enter__()
+    return ann
+
+
 class Span:
     """One timed region.  Use as a context manager; ``fence(obj)``
     registers jax arrays to block on at exit (only honoured when the
     owning tracer fences)."""
 
     __slots__ = ("name", "cat", "tid", "thread_name", "start_s", "dur_s",
-                 "attrs", "_fence_obj", "_tracer", "_t0_ns")
+                 "attrs", "_fence_obj", "_tracer", "_t0_ns", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: dict[str, Any]):
@@ -57,6 +73,7 @@ class Span:
         self._fence_obj: Any = None
         self._tracer = tracer
         self._t0_ns = 0
+        self._ann = None
 
     def fence(self, obj: Any) -> Any:
         """Register ``obj`` (pytree of jax arrays) to block on at span
@@ -65,16 +82,21 @@ class Span:
         return obj
 
     def __enter__(self) -> "Span":
+        self._ann = _annotation(self.name, self.cat, self.attrs)
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tracer
-        if self._fence_obj is not None and tr.fencing:
-            import jax
-            jax.block_until_ready(self._fence_obj)
-            self._fence_obj = None
-        end_ns = time.perf_counter_ns()
+        try:
+            if self._fence_obj is not None and tr.fencing:
+                import jax
+                jax.block_until_ready(self._fence_obj)
+                self._fence_obj = None
+            end_ns = time.perf_counter_ns()
+        finally:
+            self._ann.__exit__(*exc)
+            self._ann = None
         self.start_s = (self._t0_ns - tr._epoch_ns) * 1e-9
         self.dur_s = (end_ns - self._t0_ns) * 1e-9
         tr._record(self)
@@ -116,7 +138,7 @@ class Stopwatch:
     regardless of tracer state; a span is recorded only when tracing."""
 
     __slots__ = ("name", "cat", "attrs", "seconds", "start_s", "_tracer",
-                 "_t0_ns", "_fence_obj")
+                 "_t0_ns", "_fence_obj", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  attrs: dict[str, Any]):
@@ -128,6 +150,7 @@ class Stopwatch:
         self._tracer = tracer
         self._t0_ns = 0
         self._fence_obj: Any = None
+        self._ann = None
 
     def fence(self, obj: Any) -> Any:
         """Like ``Span.fence`` — only honoured when the tracer fences,
@@ -136,16 +159,23 @@ class Stopwatch:
         return obj
 
     def __enter__(self) -> "Stopwatch":
+        if self._tracer.enabled:
+            self._ann = _annotation(self.name, self.cat, self.attrs)
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
         tr = self._tracer
-        if self._fence_obj is not None and tr.enabled and tr.fencing:
-            import jax
-            jax.block_until_ready(self._fence_obj)
-            self._fence_obj = None
-        end_ns = time.perf_counter_ns()
+        try:
+            if self._fence_obj is not None and tr.enabled and tr.fencing:
+                import jax
+                jax.block_until_ready(self._fence_obj)
+                self._fence_obj = None
+            end_ns = time.perf_counter_ns()
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
+                self._ann = None
         self.start_s = (self._t0_ns - tr._epoch_ns) * 1e-9
         self.seconds = (end_ns - self._t0_ns) * 1e-9
         if tr.enabled:
@@ -163,12 +193,9 @@ class Tracer:
     """
 
     def __init__(self, enabled: bool = False, capacity: int = 65536,
-                 fence: bool = True, phases: bool = True):
+                 fence: bool = True):
         self.enabled = bool(enabled)
         self.fencing = bool(fence)
-        # derive per-round spatial/a2a/temporal spans from the comp-ref
-        # probe in the distributed trainer (see stream/distributed.py)
-        self.phases = bool(phases)
         self.capacity = int(capacity)
         self.recorded = 0          # total spans ever recorded
         self._spans: deque[Span] = deque(maxlen=self.capacity)
@@ -191,8 +218,9 @@ class Tracer:
     def add_span(self, name: str, start_s: float, dur_s: float,
                  cat: str = "derived", tid: int | None = None,
                  **attrs: Any) -> None:
-        """Inject a span with explicit timing (derived phases, replayed
-        measurements).  No-op when disabled."""
+        """Inject a span with explicit timing (replayed measurements).
+        Not mirrored to the profiler: it was never open.  No-op when
+        disabled."""
         if not self.enabled:
             return
         sp = Span(self, name, cat, attrs)

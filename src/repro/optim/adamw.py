@@ -14,6 +14,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.obs import stages
+
 Array = jax.Array
 
 
@@ -70,6 +72,7 @@ def global_norm(tree: Any) -> Array:
     return jnp.sqrt(jnp.sum(jnp.stack(leaves)))
 
 
+@jax.named_scope(stages.OPTIMIZER)
 def apply_updates(cfg: AdamWConfig, params: Any, grads: Any,
                   state: dict) -> tuple[Any, dict]:
     step = state["step"] + 1

@@ -52,6 +52,7 @@ from repro.core import partition
 from repro.dist import compression as compression_lib
 from repro.dist import sharding as shardlib
 from repro.ft.straggler import StepTimer
+from repro.obs import stages
 from repro.optim import adamw
 from repro.stream import encoder as enc
 from repro.stream import sharded as stream_sharded
@@ -117,6 +118,7 @@ def make_dist_stream_step(cfg: mdl.DynGNNConfig, mesh,
     carry_specs = shardlib.stream_carry_specs(cfg, axis)
     b = shardlib.stream_batch_specs(axis)
 
+    @jax.named_scope(stages.LOSS)
     def _loss_tail(nll, bsl):
         if num_seeds is None:
             total = jax.lax.psum(jnp.sum(nll), axis)
@@ -319,66 +321,6 @@ def _assemble(mesh, spec, shard_blocks, global_shape):
         global_shape, NamedSharding(mesh, spec), list(shard_blocks))
 
 
-def _dist_phase_probe(cfg, opt_cfg, params, opt_state, fr_g, assembled,
-                      lab_g, t0) -> tuple[float, float]:
-    """One-time comp-reference measurement for derived phase spans.
-
-    The round step is one fused jit, so the spatial / a2a / temporal
-    phases cannot be fenced individually inside it.  Mirror the
-    methodology of ``benchmarks/overlap_bench.pipelined_round``: compile
-    the SAME step on a single-shard mesh (where the two all-to-alls
-    degenerate to local copies) and time it on this round's actual data
-    — that is the round's communication-free compute reference.  Per
-    round, ``a2a = step - comp_ref`` and the remaining compute splits
-    between the spatial and temporal stages by their analytic flop
-    ratio (the same split the overlap benchmark feeds
-    ``round_time_model``).  Returns ``(comp_ref_s, f_spatial)``.
-    """
-    from repro.launch.mesh import make_host_mesh
-    mesh1 = make_host_mesh(data=1)
-    step1 = make_dist_stream_step(cfg, mesh1, opt_cfg)
-    host = [np.asarray(x) for x in (fr_g, *assembled, lab_g)]
-    params_h = jax.tree.map(np.asarray, params)
-    opt_h = jax.tree.map(np.asarray, opt_state)
-    carries1 = init_sharded_carries(cfg, params_h, mesh1)
-    trc = obs.get_tracer()
-
-    def run():
-        out = step1(params_h, opt_h, carries1, *host, jnp.int32(t0))
-        jax.block_until_ready(out[-1])
-
-    run()                                        # compile + warm
-    best = None
-    for _ in range(2):
-        with trc.stopwatch("round.probe", cat="probe") as sw:
-            run()
-        best = sw.seconds if best is None else min(best, sw.seconds)
-    mask = np.asarray(assembled[1])
-    e_mean = float(mask.sum()) / mask.shape[0]
-    feat = cfg.hidden
-    fl_spatial = 2 * e_mean * 2 * feat + 2 * cfg.num_nodes * feat * feat
-    fl_temporal = 2 * cfg.window * cfg.num_nodes * feat * feat
-    f_sp = fl_spatial / (fl_spatial + fl_temporal)
-    return best, f_sp
-
-
-def _emit_phase_spans(trc, gr: int, step_span, comp_ref: float,
-                      f_sp: float) -> None:
-    """Derived spatial/a2a/temporal child spans inside one measured
-    ``round.step`` span (marked ``derived`` — see docs/observability.md)."""
-    step_s = step_span.dur_s
-    a2a_s = max(step_s - comp_ref, 0.0)
-    comp_s = step_s - a2a_s
-    sp_s = f_sp * comp_s
-    t0 = step_span.start_s
-    trc.add_span("round.spatial", t0, sp_s, cat="phase.derived",
-                 round=gr, derived=True)
-    trc.add_span("round.a2a", t0 + sp_s, a2a_s, cat="phase.derived",
-                 round=gr, derived=True)
-    trc.add_span("round.temporal", t0 + sp_s + a2a_s, comp_s - sp_s,
-                 cat="phase.derived", round=gr, derived=True)
-
-
 def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
                                frames, labels, *, mesh, axis: str = "data",
                                block_size: int | None = None,
@@ -447,11 +389,12 @@ def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
     Every round is observed through ``repro.obs`` (one wall-clock
     ``round`` stopwatch per round feeding the ``step_timer`` EWMA
     watchdog — pass one to share it across elastic segments).  When the
-    global tracer is enabled the loop additionally records fenced
-    ``round.transfer`` / ``round.step`` spans plus the derived
-    spatial/a2a/temporal phase spans from the one-time comp-reference
-    probe (``_dist_phase_probe``); fencing serializes the schedule, so
-    traced runs measure the serial round (docs/observability.md).
+    global tracer is enabled the loop additionally records
+    ``round.transfer`` / ``round.step`` spans (fenced when the tracer
+    fences, which serializes the schedule) and a ``round.sync`` span
+    around each loss's host read, the round's completion record.  The
+    step's stages (spatial, a2a, temporal, ...) are named scopes on the
+    device ops, read off a profiler trace (docs/observability.md).
     """
     t_steps = len(snapshots)
     num_procs = mesh.shape[axis]
@@ -517,8 +460,9 @@ def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
                 _assemble(mesh, b["values"], (v for _, _, v in blocks),
                           (win, e_pad)))
 
-    def emit(loss_value):
-        losses.append(float(loss_value))
+    def emit(loss_value, round_idx):
+        with trc.span("round.sync", round=round_idx):
+            losses.append(float(loss_value))
         if log_fn is not None and (len(losses) - 1) % log_every == 0:
             log_fn(f"dist stream round {len(losses) - 1} "
                    f"loss {losses[-1]:.4f} "
@@ -530,14 +474,10 @@ def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
     stopped = False
     timer = step_timer if step_timer is not None else StepTimer()
     trc = obs.get_tracer()
-    # derived phase spans need fenced (execution-timed) measurements and
-    # the comp-reference probe; both are opt-in via the tracer config
-    derive_phases = trc.enabled and trc.phases and trc.fencing
-    probe: tuple[float, float] | None = None      # (comp_ref_s, f_spatial)
     obs.inc("stream.payload_bytes", sum(per_shard_bytes))
     # span round index: monotonic across epochs (the model-time index
     # ``gr`` deliberately restarts each epoch, which would collide trace
-    # rounds and calibration keys)
+    # rounds)
     ridx = start_round
     for _ in range(num_epochs):
         host = dist_round_stream(shard_streams, frames, labels, win, bsl,
@@ -558,7 +498,7 @@ def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
         # are an optimization state of the quantizer, not model state
         comm_res = (init_comm_residuals(cfg, win, mesh, axis)
                     if use_comp else None)
-        in_flight = None        # round r-1's device loss (pipeline_rounds)
+        in_flight = None        # round r-1's (device loss, round index)
         try:
             for r, (items, fr_g, lab_g) in enumerate(rounds):
                 gr = start_round + r
@@ -585,24 +525,18 @@ def train_distributed_streamed(cfg: mdl.DynGNNConfig, snapshots, values,
                         # so they execute while the host blocks on loss
                         # r-1.
                         if in_flight is not None:
-                            emit(in_flight)
-                        in_flight = loss
+                            emit(*in_flight)
+                        in_flight = (loss, ridx)
                     else:
-                        emit(loss)
+                        emit(loss, ridx)
                 obs.inc("stream.rounds")
                 timer.observe(round_sw.seconds)  # counts straggler.flags
-                if derive_phases:
-                    if probe is None:
-                        probe = _dist_phase_probe(
-                            cfg, opt_cfg, params, opt_state, fr_g,
-                            assembled, lab_g, gr * win)
-                    _emit_phase_spans(trc, ridx, st_sp, *probe)
                 ridx += 1
                 if stop_fn is not None and stop_fn(gr):
                     stopped = True
                     break
             if in_flight is not None:   # drain the pipelined epoch tail
-                emit(in_flight)
+                emit(*in_flight)
         finally:
             if isinstance(rounds, PrefetchIterator):
                 rounds.close()

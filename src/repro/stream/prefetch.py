@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from repro import obs, sanitize
 from repro.core import graphdiff
 from repro.core.graphdiff import FullSnapshot, SnapshotDelta
+from repro.obs import stages
 from repro.stream.wire import QuantizedDelta
 
 _SENTINEL = object()
@@ -74,8 +75,12 @@ class PrefetchIterator:
     def _worker(self, it: Iterator) -> None:
         trc = obs.get_tracer()
         try:
-            for item in it:
-                if self._stop.is_set():
+            while True:
+                # the host iterator's work for one item (delta encode,
+                # frame, labels), and the last pull that finds the end
+                with trc.span("prefetch.encode", cat="prefetch"):
+                    item = next(it, _SENTINEL)
+                if item is _SENTINEL or self._stop.is_set():
                     return
                 # staging span lives on the worker thread's trace track,
                 # so overlap with the consumer's round spans is visible
@@ -168,6 +173,7 @@ _APPLY_DONATING = jax.jit(graphdiff.apply_delta, donate_argnums=(0, 1))
 _APPLY_PLAIN = jax.jit(graphdiff.apply_delta)
 
 
+@jax.named_scope(stages.DELTA_APPLY)
 def _decode_apply(prev_edges, prev_mask, drop_pos, drop_mask, add_edges,
                   add_mask):
     """Widen a QuantizedDelta's narrow wire dtypes on device, then apply

@@ -20,6 +20,7 @@ The overlap path's win is measured in ``benchmarks/overlap_bench.py``.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import partial
 
@@ -27,8 +28,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import models as mdl
 from repro.graph import segment
+from repro.obs import stages
 from repro.optim import adamw
 from repro.stream import encoder as enc
 from repro.stream.prefetch import (DeltaApplier, PrefetchIterator,
@@ -75,7 +78,7 @@ def make_stream_train_step(cfg: mdl.DynGNNConfig,
             z, new_carries = advance_slice(cfg, p, carries, frame[None],
                                            edges[None], mask[None],
                                            values[None], t_offset)
-            return jnp.mean(slice_nll(p, z[0], labels)), new_carries
+            return mean_nll(p, z[0], labels), new_carries
 
         (loss, new_carries), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
@@ -86,12 +89,14 @@ def make_stream_train_step(cfg: mdl.DynGNNConfig,
     return step
 
 
+@jax.named_scope(stages.EDGE_WEIGHTS)
 def make_self_loops(n: int) -> tuple[jax.Array, jax.Array]:
     """Device-resident self-loop edge list + unit mask/values for N nodes."""
     return (jnp.stack([jnp.arange(n, dtype=jnp.int32)] * 2, axis=1),
             jnp.ones((n,), dtype=jnp.float32))
 
 
+@jax.named_scope(stages.EDGE_WEIGHTS)
 def slice_weights_with_loops(n: int, loop_edges, loop_ones, edges, mask,
                              values) -> tuple[jax.Array, jax.Array]:
     """Append self-loops to a (k, E, 2) slice of reconstructed snapshots
@@ -114,11 +119,18 @@ def slice_weights_with_loops(n: int, loop_edges, loop_ones, edges, mask,
     return e_full, w_full
 
 
+@jax.named_scope(stages.LOSS)
 def slice_nll(params: dict, z, labels) -> jax.Array:
     """Per-(t, u) CE against the shared classifier (float32 softmax)."""
     logits = mdl.classify(params, z)
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+
+
+@jax.named_scope(stages.LOSS)
+def mean_nll(params: dict, z, labels) -> jax.Array:
+    """The streamed steps' loss: mean CE over every (t, u)."""
+    return jnp.mean(slice_nll(params, z, labels))
 
 
 def make_stream_slice_step(cfg: mdl.DynGNNConfig,
@@ -140,7 +152,7 @@ def make_stream_slice_step(cfg: mdl.DynGNNConfig,
         def loss_fn(p):
             z, new_carries = advance_slice(cfg, p, carries, frames, edges,
                                            mask, values, t_offset)
-            return jnp.mean(slice_nll(p, z, labels)), new_carries
+            return mean_nll(p, z, labels), new_carries
 
         (loss, new_carries), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(params)
@@ -240,45 +252,51 @@ def train_streamed(cfg: mdl.DynGNNConfig, snapshots, values, frames,
                          f"length {t_steps}")
 
     losses: list[float] = []
-    for _ in range(num_epochs):
-        host = round_host_stream(mk_host(), slice_len) if sliced \
-            else mk_host()
-        if overlap:
-            items = PrefetchIterator(host, depth=prefetch_depth)
-        else:
-            items = (stage_item(x) for x in host)
-        applier = DeltaApplier(max_edges)
-        carries = mdl.init_carries(cfg, params)
-        try:
-            if sliced:
-                stacker = SlotStacker(slice_len)
-                for r, (slice_items, frame_b, lab_b) in enumerate(items):
-                    for j, item in enumerate(slice_items):
-                        edges, mask, vals = applier.consume(item)
-                        stacker.put(j, edges, mask, vals)
-                    e_b, m_b, v_b = stacker.arrays()
-                    params, opt_state, carries, loss = step_fn(
-                        params, opt_state, carries, frame_b, e_b, m_b,
-                        v_b, lab_b, jnp.int32(r * slice_len))
-                    losses.append(float(loss))
-                    if log_fn is not None \
-                            and (len(losses) - 1) % log_every == 0:
-                        log_fn(f"stream slice {len(losses) - 1} "
-                               f"loss {losses[-1]:.4f}")
-            else:
+    trc = obs.get_tracer()
+    for epoch in range(num_epochs):
+        items = None
+        with contextlib.ExitStack() as epoch_start:
+            # open from the epoch's start until its first item is in hand:
+            # the new prefetch worker's first encode + transfer, the ring
+            # and the carries
+            epoch_start.enter_context(
+                trc.span("stream.epoch_start", epoch=epoch))
+            try:
+                host = round_host_stream(mk_host(), slice_len) if sliced \
+                    else mk_host()
+                if overlap:
+                    items = PrefetchIterator(host, depth=prefetch_depth)
+                else:
+                    items = (stage_item(x) for x in host)
+                applier = DeltaApplier(max_edges)
+                carries = mdl.init_carries(cfg, params)
+                stacker = SlotStacker(slice_len) if sliced else None
                 for t, (item, frame, lab) in enumerate(items):
-                    edges, mask, vals = applier.consume(item)
-                    params, opt_state, carries, loss = step_fn(
-                        params, opt_state, carries, frame, edges, mask,
-                        vals, lab, jnp.int32(t))
-                    losses.append(float(loss))
+                    if t == 0:
+                        epoch_start.close()
+                    with trc.span("stream.apply", epoch=epoch, step=t):
+                        if sliced:
+                            for j, sub in enumerate(item):
+                                stacker.put(j, *applier.consume(sub))
+                            edges, mask, vals = stacker.arrays()
+                        else:
+                            edges, mask, vals = applier.consume(item)
+                    with trc.span("stream.step", epoch=epoch, step=t):
+                        params, opt_state, carries, loss = step_fn(
+                            params, opt_state, carries, frame, edges, mask,
+                            vals, lab, jnp.int32(t * (slice_len if sliced
+                                                      else 1)))
+                    # the step's completion record: its loss reached the host
+                    with trc.span("stream.sync", epoch=epoch, step=t):
+                        losses.append(float(loss))
+                    obs.inc("stream.steps")
                     if log_fn is not None \
                             and (len(losses) - 1) % log_every == 0:
-                        log_fn(f"stream step {len(losses) - 1} "
-                               f"loss {losses[-1]:.4f}")
-        finally:
-            # unblock + retire the prefetch worker if the step raised
-            if isinstance(items, PrefetchIterator):
-                items.close()
+                        log_fn(f"stream {'slice' if sliced else 'step'} "
+                               f"{len(losses) - 1} loss {losses[-1]:.4f}")
+            finally:
+                # unblock + retire the prefetch worker if the step raised
+                if isinstance(items, PrefetchIterator):
+                    items.close()
     return StreamTrainState(params=params, opt_state=opt_state,
                             losses=losses)

@@ -1,10 +1,12 @@
 """repro.obs contract tests: tracer semantics (nesting, ring bounding,
-thread safety, the disabled no-op), metrics registry deltas, Perfetto
-export round-trip + schema validation, calibration against
-``round_time_model``, and the end-to-end traced streamed_mesh fit that
-the CI trace-smoke step gates on."""
+thread safety, the disabled no-op, the profiler mirror), metrics
+registry deltas, Perfetto export round-trip + schema validation, the
+step's stage scopes in the compiled HLO, and the end-to-end traced fits
+(per-snapshot spans on one device, per-round spans on the mesh that the
+CI trace-smoke step gates on)."""
 
 import json
+import re
 import threading
 
 import numpy as np
@@ -196,64 +198,12 @@ def test_validate_trace_catches_malformed_events(tmp_path):
     assert obs.validate_trace(events)
 
 
-# ---------------------------------------------------------- calibration ----
-
-def _synthetic_round_spans(trc, r, transfer, spatial, a2a, temporal,
-                           extra=0.0):
-    t0 = float(r)
-    total = transfer + spatial + a2a + temporal + extra
-    trc.add_span("round", t0, total, cat="round", round=r)
-    off = 0.0
-    for name, dur in (("transfer", transfer), ("spatial", spatial),
-                      ("a2a", a2a), ("temporal", temporal)):
-        trc.add_span(f"round.{name}", t0 + off, dur, round=r)
-        off += dur
-
-
-def test_calibration_zero_residual_on_model_exact_rounds():
-    trc = Tracer(enabled=True, fence=False)
-    for r in range(3):
-        _synthetic_round_spans(trc, r, 0.010, 0.020, 0.008, 0.030)
-    rep = obs.calibration_report(trc.spans())
-    assert len(rep.rows) == 3 and rep.extra["skipped"] == 0
-    for row in rep.rows:
-        assert abs(row.residual_s) < 1e-9        # serial model is the sum
-        assert all(abs(v) < 1e-9
-                   for v in row.phase_residual_s.values())
-    assert rep.baseline_s["spatial"] == pytest.approx(0.020)
-    assert "3 rounds" in rep.summary()
-
-
-def test_calibration_flags_straggler_phase_and_skips_incomplete():
-    trc = Tracer(enabled=True, fence=False)
-    for r in range(4):
-        a2a = 0.008 if r != 2 else 0.020         # round 2 lost time in a2a
-        _synthetic_round_spans(trc, r, 0.010, 0.020, a2a, 0.030)
-    trc.add_span("round", 9.0, 0.1, cat="round", round=9)  # phases missing
-    rep = obs.calibration_report(trc.spans())
-    assert rep.extra["skipped"] == 1
-    row = next(r_ for r_ in rep.rows if r_.round == 2)
-    assert row.phase_residual_s["a2a"] == pytest.approx(0.012)
-    assert row.phase_residual_s["temporal"] == pytest.approx(0.0)
-
-
-def test_calibration_accepts_loaded_trace_events(tmp_path):
-    trc = Tracer(enabled=True, fence=False)
-    _synthetic_round_spans(trc, 0, 0.010, 0.020, 0.008, 0.030)
-    path = tmp_path / "t.json"
-    obs.export_trace(path, tracer=trc, metrics={})
-    events, _ = obs.load_trace(path)
-    rep = obs.calibration_report(events)
-    assert len(rep.rows) == 1
-    assert rep.rows[0].predicted_s == pytest.approx(0.068, rel=1e-6)
-
-
 # ------------------------------------------------------------------ e2e ----
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 host devices")
 def test_traced_streamed_mesh_fit_exports_full_phase_coverage(tmp_path):
-    """The acceptance path: a traced 4-shard fit yields all four
-    round_time_model phases for every round, prefetch thread spans,
+    """The acceptance path: a traced 4-shard fit yields the transfer,
+    step and sync spans of every round, prefetch thread spans,
     RunResult.metrics, and a valid exported trace."""
     prev = obs.get_tracer()
     obs.configure(enabled=True)
@@ -267,15 +217,18 @@ def test_traced_streamed_mesh_fit_exports_full_phase_coverage(tmp_path):
         result = Engine(RunConfig(model=cfg, data=data, plan=plan)).fit()
 
         trc = obs.get_tracer()
-        per_round = obs.phase_durations(trc.spans())
+        per_round: dict[int, set] = {}
+        for sp in trc.spans():
+            if "round" in sp.attrs:
+                per_round.setdefault(sp.attrs["round"], set()).add(sp.name)
         rounds = sorted(per_round)
-        assert len(rounds) == 2 * NB
+        assert rounds == list(range(2 * NB))
         for r in rounds:
-            missing = [p for p in obs.PHASES if p not in per_round[r]]
-            assert not missing, f"round {r} missing phases {missing}"
-            assert "round" in per_round[r]
+            assert per_round[r] >= {"round", "round.transfer", "round.step",
+                                    "round.sync"}, (r, per_round[r])
         names = {s.name for s in trc.spans()}
-        assert {"prefetch.stage", "prefetch.wait", "round.step"} <= names
+        assert {"prefetch.encode", "prefetch.stage", "prefetch.wait",
+                "round.step"} <= names
 
         # session-scoped metrics landed on the result
         m = result.metrics
@@ -283,17 +236,15 @@ def test_traced_streamed_mesh_fit_exports_full_phase_coverage(tmp_path):
         assert m["counters"]["prefetch.items"] >= 2 * NB
         assert m["counters"]["stream.payload_bytes"] > 0
         assert m["spans"]["round"]["count"] == 2 * NB
-
-        # calibration joins every complete round against the model
-        rep = obs.calibration_report(trc.spans())
-        assert len(rep.rows) == 2 * NB
-        assert all(row.predicted_s > 0 for row in rep.rows)
+        assert m["spans"]["round.sync"]["count"] == 2 * NB
 
         # and the whole thing survives the CI export -> check path
         path = tmp_path / "trace.json"
         obs.export_trace(path)
         events, _ = obs.load_trace(path)
         assert obs.validate_trace(events) == []
+        from tools.check_trace import check
+        assert check(str(path), ["prefetch.encode", "round.step"]) == []
     finally:
         obs.set_tracer(prev)
 
@@ -314,3 +265,179 @@ def test_untraced_fit_records_no_spans_but_still_counts():
     assert result.metrics is not None
     assert result.metrics["spans"] == {}
     assert np.isfinite(result.losses).all()
+
+
+# ------------------------------------------------------ profiler mirror ----
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs every enter
+    and exit with the annotation's name and metadata."""
+
+    def __init__(self):
+        self.log: list[tuple] = []
+
+    def __call__(self, name, **meta):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name, meta))
+                return self
+
+            def __exit__(self, *exc):
+                log.append(("exit", name, meta))
+
+        return _Ann()
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    rec = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", rec)
+    return rec
+
+
+def test_span_mirrors_a_trace_annotation_only_when_enabled(annotations):
+    with Tracer(enabled=False).span("quiet", round=1):
+        pass
+    assert annotations.log == []
+    with Tracer(enabled=True, fence=False).span("loud", cat="c", round=1):
+        assert annotations.log == [("enter", "loud",
+                                    {"cat": "c", "round": 1})]
+    assert annotations.log[-1] == ("exit", "loud", {"cat": "c", "round": 1})
+
+
+def test_stopwatch_mirrors_a_trace_annotation_only_when_enabled(
+        annotations):
+    with Tracer(enabled=False).stopwatch("quiet") as sw:
+        pass
+    assert sw.seconds >= 0 and annotations.log == []
+    with Tracer(enabled=True, fence=False).stopwatch("loud", step=2):
+        pass
+    assert [(k, n) for k, n, _ in annotations.log] == [("enter", "loud"),
+                                                       ("exit", "loud")]
+    assert annotations.log[0][2] == {"cat": "phase", "step": 2}
+
+
+def test_configure_refuses_derived_phases():
+    prev = obs.get_tracer()
+    try:
+        # accepted from callers written before the derivation went away
+        assert obs.configure(enabled=False, phases=False).enabled is False
+        with pytest.raises(TypeError, match="phases"):
+            obs.configure(enabled=True, phases=True)
+    finally:
+        obs.set_tracer(prev)
+
+
+# --------------------------------------------------------- stage scopes ----
+
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_WRAPPED = re.compile(r"^[\w.]+\((.*)\)$")
+
+
+def _stages(op_name: str) -> list[str]:
+    """The name-stack components of ``op_name`` that are stages, outer
+    first, with jvp/transpose/... wrappers taken off."""
+    out = []
+    for part in op_name.split("/"):
+        while (m := _WRAPPED.match(part)):
+            part = m.group(1)
+        if part in obs.STAGES:
+            out.append(part)
+    return out
+
+
+def _op_names(hlo: str) -> list[str]:
+    return _OP_NAME.findall(hlo)
+
+
+@pytest.mark.parametrize("model,expected", [
+    ("tmgcn", {"edge_weights", "spatial", "spmm", "temporal", "loss",
+               "optimizer"}),
+    ("cdgcn", {"edge_weights", "spatial", "spmm", "temporal", "loss",
+               "optimizer"}),
+    ("evolvegcn", {"edge_weights", "spatial", "spmm", "loss",
+                   "optimizer"}),
+])
+def test_snapshot_step_ops_carry_their_stage(model, expected):
+    """Every stage of the model names device ops of the compiled
+    per-snapshot step, and the backward ops of the spatial stage sit
+    under ``transpose(jvp(spatial))``."""
+    import jax.numpy as jnp
+    from repro.core import models as mdl
+    from repro.optim import adamw
+    from repro.stream import train_loop as tl
+    n, e = 32, 64
+    cfg = DynGNNConfig(model=model, num_nodes=n, num_steps=4, window=3)
+    opt = adamw.AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=4)
+    params = mdl.init_params(jax.random.PRNGKey(0), cfg)
+    step = tl.make_stream_train_step(cfg, opt)
+    hlo = step.lower(
+        params, adamw.init_state(params), mdl.init_carries(cfg, params),
+        jnp.zeros((n, cfg.feat_in)), jnp.zeros((e, 2), jnp.int32),
+        jnp.ones((e,)), jnp.ones((e,)), jnp.zeros((n,), jnp.int32),
+        jnp.int32(0)).compile().as_text()
+    names = _op_names(hlo)
+    seen = {st for name in names for st in _stages(name)}
+    assert expected <= seen, expected - seen
+    assert any("transpose(jvp(spatial))" in name for name in names)
+    if model != "evolvegcn":
+        assert not {"a2a", "delta_apply"} & seen
+
+
+@pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 host devices")
+@pytest.mark.parametrize("compression", ["none", "int8_a2a"])
+def test_every_mesh_all_to_all_is_under_a2a(compression):
+    from repro.launch.mesh import make_host_mesh
+    from repro.stream.distributed import lowered_step_hlo
+    cfg = DynGNNConfig(model="tmgcn", num_nodes=32, num_steps=8, window=3,
+                       checkpoint_blocks=2)
+    hlo = lowered_step_hlo(cfg, make_host_mesh(data=4, model=1), win=4,
+                           max_edges=64, compression=compression)
+    a2a = [line for line in hlo.splitlines() if " all-to-all(" in line]
+    assert a2a, "the mesh step lost its all-to-alls"
+    for line in a2a:
+        (name,) = _OP_NAME.findall(line)
+        assert _stages(name)[-1:] == ["a2a"], line
+
+
+def test_apply_delta_ops_are_under_delta_apply():
+    import jax.numpy as jnp
+    from repro.core import graphdiff
+    e = 16
+    hlo = jax.jit(graphdiff.apply_delta).lower(
+        jnp.zeros((e, 2), jnp.int32), jnp.ones((e,)),
+        jnp.zeros((4,), jnp.int32), jnp.ones((4,)),
+        jnp.zeros((4, 2), jnp.int32), jnp.ones((4,))).compile().as_text()
+    names = [n for n in _op_names(hlo) if "/" in n]
+    assert names and all(_stages(n) == ["delta_apply"] for n in names)
+
+
+def test_traced_streamed_fit_records_per_snapshot_spans():
+    """One device, per-snapshot schedule: each snapshot records its
+    apply, step and sync spans and one ``stream.steps`` count; the
+    prefetch worker records one encode per item plus the pull that
+    finds the stream's end; each epoch records one ``stream.epoch_start``."""
+    prev = obs.get_tracer()
+    obs.configure(enabled=True, fence=False)
+    try:
+        cfg = DynGNNConfig(model="tmgcn", num_nodes=N, num_steps=T,
+                           window=3, checkpoint_blocks=NB)
+        data = SyntheticTrace(num_nodes=N, num_steps=T, density=2.0,
+                              churn=0.1, smoothing_mode="mproduct",
+                              window=3)
+        plan = ExecutionPlan(mode="streamed", shards=1, num_epochs=1)
+        result = Engine(RunConfig(model=cfg, data=data, plan=plan,
+                                  log_fn=lambda s: None)).fit()
+        spans = result.metrics["spans"]
+        for name in ("stream.apply", "stream.step", "stream.sync"):
+            assert spans[name]["count"] == T, name
+        assert spans["prefetch.encode"]["count"] == T + 1
+        assert spans["stream.epoch_start"]["count"] == 1
+        assert result.metrics["counters"]["stream.steps"] == T
+        steps = [s.attrs for s in obs.get_tracer().spans()
+                 if s.name == "stream.step"]
+        assert steps == [{"epoch": 0, "step": t} for t in range(T)]
+    finally:
+        obs.set_tracer(prev)

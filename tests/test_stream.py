@@ -329,6 +329,33 @@ def test_prefetch_training_losses_bit_identical(model):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("overlap", [False, True])
+def test_streamed_loop_lets_each_item_go_after_its_step(overlap):
+    """The loop keeps no staged item past the steps that follow it: a
+    reference kept on the epoch's first item would hold its frame,
+    labels and edge values on the device all epoch."""
+    import weakref
+    from repro.data.dyngnn import synthetic_dataset
+    from repro.optim import adamw
+    ds = synthetic_dataset(48, 8, density=2.0, churn=0.1,
+                           smoothing_mode="mproduct", window=3, seed=0)
+    cfg = DynGNNConfig(model="tmgcn", num_nodes=48, num_steps=8, window=3,
+                       checkpoint_blocks=2)
+    step = stream_train.make_stream_train_step(
+        cfg, adamw.AdamWConfig(lr=1e-2, warmup_steps=1, total_steps=8))
+    frames_seen, alive = [], []
+
+    def recording_step(*args):
+        frames_seen.append(weakref.ref(args[3]))
+        alive.append(sum(r() is not None for r in frames_seen[:-3]))
+        return step(*args)
+
+    stream_train.train_streamed(
+        cfg, ds.snapshots, ds.values, np.asarray(ds.frames),
+        np.asarray(ds.labels), overlap=overlap, step_fn=recording_step)
+    assert len(alive) == 8 and alive == [0] * 8
+
+
 def test_pipeline_uses_stream_encoder_and_accounts_bytes():
     from repro.data.dyngnn import DTDGPipeline, synthetic_dataset
     ds = synthetic_dataset(64, 16, density=2.0, churn=0.1,

@@ -4,16 +4,16 @@ assert the spans a healthy run must contain.
 
 CI's trace-smoke step runs a short streamed_mesh fit with ``--trace``
 and then gates on this script: the trace must be a valid Chrome-trace /
-Perfetto file (``repro.obs.validate_trace``), every completed round must
-carry all four round-phase spans (``round.transfer`` / ``round.spatial``
-/ ``round.a2a`` / ``round.temporal`` — the phases
-``round_time_model`` predicts), and any ``--require``'d span names
-(e.g. the prefetch staging threads) must be present.
+Perfetto file (``repro.obs.validate_trace``), every streamed ``round``
+span must come with the round's ``round.transfer``, ``round.step`` and
+``round.sync`` spans (reconstruction, step dispatch, and the loss read
+that completes the round), and any ``--require``'d span names (e.g. the
+prefetch staging threads) must be present.
 
 Usage::
 
     python tools/check_trace.py trace.json \
-        --phases --require prefetch.stage --require prefetch.wait
+        --require prefetch.stage --require prefetch.wait
 
 Exits non-zero with one line per problem.
 """
@@ -26,10 +26,14 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "src"))
 
-from repro.obs import PHASES, load_trace, validate_trace  # noqa: E402
+from repro.obs import load_trace, validate_trace  # noqa: E402
+
+# the spans every round of a traced streamed_mesh fit records (the
+# sampled schedule's ``round`` spans, marked schedule=sampled, have none)
+ROUND_SPANS = ("round.transfer", "round.step", "round.sync")
 
 
-def check(path: str, require: list[str], phases: bool) -> list[str]:
+def check(path: str, require: list[str]) -> list[str]:
     events, meta = load_trace(path)
     problems = [f"{path}: {p}" for p in validate_trace(events)]
     if problems:
@@ -39,33 +43,19 @@ def check(path: str, require: list[str], phases: bool) -> list[str]:
         if name not in names:
             problems.append(f"{path}: required span {name!r} missing "
                             f"(have {sorted(names)})")
-    if phases:
-        rounds = sorted({ev["args"]["round"] for ev in events
-                         if ev["name"] == "round"
-                         and "round" in ev.get("args", {})})
-        if not rounds:
-            problems.append(f"{path}: no 'round' spans — not a traced "
-                            "streamed run?")
-        for r in rounds:
-            have = {ev["name"] for ev in events
-                    if ev.get("args", {}).get("round") == r}
-            missing = [f"round.{p}" for p in PHASES
-                       if f"round.{p}" not in have]
-            # the last round may be cut off mid-flight (preemption /
-            # stop_fn) — phases are derived after the step completes
-            if missing and r != rounds[-1]:
-                problems.append(f"{path}: round {r} missing phase spans "
-                                f"{missing}")
-        if len(rounds) >= 2 and meta.get("dropped_spans", 0) == 0:
-            # with no ring overflow, every complete round must be whole —
-            # including the last one when the run wasn't cut short
-            have = {ev["name"] for ev in events
-                    if ev.get("args", {}).get("round") == rounds[-1]}
-            missing = [f"round.{p}" for p in PHASES
-                       if f"round.{p}" not in have]
-            if missing:
-                problems.append(f"{path}: final round {rounds[-1]} missing "
-                                f"phase spans {missing}")
+    rounds = sorted({ev["args"]["round"] for ev in events
+                     if ev["name"] == "round"
+                     and "round" in ev.get("args", {})
+                     and ev["args"].get("schedule") != "sampled"})
+    for r in rounds:
+        have = {ev["name"] for ev in events
+                if ev.get("args", {}).get("round") == r}
+        missing = [n for n in ROUND_SPANS if n not in have]
+        # a cut-off tail (preemption / stop_fn, or ring overflow) may
+        # lose the last round's spans; every other round must be whole
+        if missing and (r != rounds[-1]
+                        or meta.get("dropped_spans", 0) == 0):
+            problems.append(f"{path}: round {r} missing spans {missing}")
     return problems
 
 
@@ -75,11 +65,8 @@ def main() -> int:
     ap.add_argument("--require", action="append", default=[],
                     metavar="NAME", help="span name that must be present "
                     "(repeatable)")
-    ap.add_argument("--phases", action="store_true",
-                    help="assert all four round_time_model phase spans "
-                    "(transfer/spatial/a2a/temporal) on every round")
     args = ap.parse_args()
-    problems = check(args.trace, args.require, args.phases)
+    problems = check(args.trace, args.require)
     for p in problems:
         print(p, file=sys.stderr)
     if not problems:

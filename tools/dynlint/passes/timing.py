@@ -6,7 +6,7 @@ Invariant (PR 10): performance timing inside ``src/`` goes through
 clock, shows up in exported traces, and disappears when the tracer is
 off.  Raw monotonic-clock reads (``time.perf_counter[_ns]`` /
 ``time.monotonic[_ns]``) scattered through the code produce numbers no
-trace can see and no calibration can join.
+trace can see.
 
 Flagged: any call to those four functions in ``src/`` files, whether
 via the module (``time.perf_counter()``, including ``import time as
