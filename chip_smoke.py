@@ -47,11 +47,8 @@ KERNEL_ATOL = 1e-5
 # Served scores vs the offline forward: both on the chip, same math in a
 # different program (per-window steps vs one blocked scan).
 SERVE_ATOL = 1e-4
-# kernel phase: segment_spmm pads F to 128 lanes, so it runs on a graph
-# cut to 65,536 vertices at the trace's ~2.78 edges per vertex
-SPMM_NODES = 65_536
+# kernel phase: segment.spmm runs at the trace's ~2.78 edges per vertex
 SPMM_EDGES_PER_NODE = 2.78
-SPMM_EDGES_PER_BLOCK = 4096
 SERVE_WINDOWS, SERVE_BLOCK, SERVE_EVENTS = 4, 2, 300_000
 
 
@@ -106,10 +103,10 @@ def device_phase(chips: int):
 
 def kernel_phase(n_nodes: int, cfg) -> None:
     """Both Pallas kernels compiled for the chip, against f64 numpy."""
+    from repro.graph import segment
     from repro.kernels.common import resolve_interpret
     from repro.kernels.mproduct.mproduct import banded_ttm
     from repro.kernels.mproduct.ref import m_matrix
-    from repro.kernels.segment_spmm import ops as spmm_ops
 
     interpret = resolve_interpret(None)     # False: checked by device_phase
 
@@ -126,28 +123,22 @@ def kernel_phase(n_nodes: int, cfg) -> None:
         f"Mosaic kernel={mosaic}, max abs err vs f64 {err!r}")
     check(mosaic and err <= KERNEL_ATOL, f"banded_ttm err {err}")
 
-    n = SPMM_NODES
+    n = n_nodes
     e = int(n * SPMM_EDGES_PER_NODE)
     edges = rng.integers(0, n, size=(e, 2)).astype(np.int32)
     w = rng.standard_normal(e).astype(np.float32)
     feats = rng.standard_normal((n, cfg.hidden)).astype(np.float32)
-    over = int(spmm_ops.bucket_overflow_count(
-        edges, w, n, jnp.int32(SPMM_EDGES_PER_BLOCK)))
-    spmm = jax.jit(lambda a, b, c: spmm_ops.segment_spmm(
-        a, b, c, n, node_block=128, feat_block=128,
-        edges_per_block=SPMM_EDGES_PER_BLOCK, interpret=interpret))
+    # on a TPU, segment.spmm sorts and reduces with the Pallas kernels
+    spmm = jax.jit(lambda a, b, c: segment.spmm(a, b, c, n))
     mosaic = "tpu_custom_call" in spmm.lower(feats, edges, w).as_text()
     got = np.asarray(spmm(feats, edges, w))
     want = np.zeros((n, cfg.hidden))
     np.add.at(want, edges[:, 1], feats[edges[:, 0]].astype(np.float64)
               * w[:, None])
     err = float(np.max(np.abs(got - want)))
-    log(f"kernel segment_spmm: N={n} E={e} F={cfg.hidden} node_block=128 "
-        f"feat_block=128 edges_per_block={SPMM_EDGES_PER_BLOCK}, "
-        f"overflow={over}, Mosaic kernel={mosaic}, max abs err vs f64 "
-        f"{err!r}")
-    check(mosaic and over == 0 and err <= KERNEL_ATOL,
-          f"segment_spmm err {err}, overflow {over}")
+    log(f"kernel segment.spmm: N={n} E={e} F={cfg.hidden}, "
+        f"Mosaic kernel={mosaic}, max abs err vs f64 {err!r}")
+    check(mosaic and err <= KERNEL_ATOL, f"segment.spmm err {err}")
 
 
 def train_phase(cfg, data, opt, clock: CompileClock):

@@ -1,8 +1,9 @@
 """GCN spatial module (Kipf-Welling, Eq. 2) over padded snapshots.
 
 The sparse-dense aggregate ``A_tilde @ X`` is the compute hot spot; it is
-served either by the XLA-native segment-sum path or by the Pallas TPU kernel
-(``repro.kernels.segment_spmm``), selected with ``use_pallas``.
+served by ``repro.graph.segment.spmm``, which sorts its lanes by destination
+and reduces them with the Pallas TPU kernel (``repro.kernels.segment_spmm``)
+on a TPU.
 """
 
 from __future__ import annotations
@@ -30,25 +31,14 @@ def init_gcn_params(key: Array, f_in: int, f_out: int,
 
 @jax.named_scope(stages.SPMM)
 def spatial_aggregate(x: Array, edges: Array, edge_weights: Array,
-                      num_nodes: int, use_pallas: bool = False,
-                      interpret: bool | None = None) -> Array:
-    """``A_tilde @ X`` for one snapshot. x: (N, F) -> (N, F).
-
-    ``interpret=None`` lets the kernel wrapper resolve from the backend
-    (interpret only on CPU); pass an explicit bool to force either mode.
-    """
-    if use_pallas:
-        from repro.kernels.segment_spmm import ops as spmm_ops
-        return spmm_ops.segment_spmm(x, edges, edge_weights, num_nodes,
-                                     interpret=interpret)
+                      num_nodes: int) -> Array:
+    """``A_tilde @ X`` for one snapshot. x: (N, F) -> (N, F)."""
     return segment.spmm(x, edges, edge_weights, num_nodes)
 
 
 def gcn_apply(params: dict, x: Array, edges: Array, edge_weights: Array,
               num_nodes: int, *, activation: Callable = jax.nn.relu,
-              concat_skip: bool = False, use_pallas: bool = False,
-              interpret: bool | None = None,
-              pre_aggregated: bool = False) -> Array:
+              concat_skip: bool = False, pre_aggregated: bool = False) -> Array:
     """One GCN op on one snapshot.
 
     concat_skip implements CD-GCN's skip connection (§5.1):
@@ -57,7 +47,7 @@ def gcn_apply(params: dict, x: Array, edges: Array, edge_weights: Array,
     pre-computation, §5.5) — skip the sparse product.
     """
     y0 = x if pre_aggregated else spatial_aggregate(
-        x, edges, edge_weights, num_nodes, use_pallas, interpret)
+        x, edges, edge_weights, num_nodes)
     y1 = y0 @ params["w"] + params["b"]
     if concat_skip:
         return activation(jnp.concatenate([y0, y1], axis=-1))

@@ -42,7 +42,7 @@ class DynGNNConfig:
     num_classes: int = 2
     # execution knobs
     checkpoint_blocks: int = 1      # nb (1 = no checkpointing)
-    use_pallas: bool = False
+    use_pallas: bool = False        # the M-product's Pallas kernel
     precompute_first_agg: bool = False  # paper §5.5 first-layer SpMM reuse
     param_dtype: Any = jnp.float32
 
@@ -144,8 +144,7 @@ def spatial_stage(cfg: DynGNNConfig, layer_params: dict, _layer: int,
             layer_params["evolve"], w_prev, state, x.shape[0])
 
         def per_step(xt, et, wt, w_t):
-            y0 = gcnlib.spatial_aggregate(xt, et, wt, num_nodes,
-                                          cfg.use_pallas)
+            y0 = gcnlib.spatial_aggregate(xt, et, wt, num_nodes)
             return jax.nn.relu(y0 @ w_t)
 
         y = jax.vmap(per_step)(x, edges, edge_weights, ws)
@@ -156,7 +155,7 @@ def spatial_stage(cfg: DynGNNConfig, layer_params: dict, _layer: int,
     def per_step(xt, et, wt):
         return gcnlib.gcn_apply(
             layer_params["gcn"], xt, et, wt, num_nodes,
-            concat_skip=concat_skip, use_pallas=cfg.use_pallas,
+            concat_skip=concat_skip,
             activation=(lambda v: v) if cfg.model == "tmgcn"
             else jax.nn.relu)
 

@@ -165,8 +165,7 @@ def _sp_block_body(cfg: mdl.DynGNNConfig, params: dict, axis,
             # carried block-boundary state (weights are tiny — §5.5), then
             # slices its own bsl steps.
             def per_step(xt, et, wt, w_t):
-                y0 = mdl.gcnlib.spatial_aggregate(xt, et, wt, xt.shape[0],
-                                                  cfg.use_pallas)
+                y0 = mdl.gcnlib.spatial_aggregate(xt, et, wt, xt.shape[0])
                 return jax.nn.relu(y0 @ w_t)
 
             with jax.named_scope(stages.SPATIAL):

@@ -1,10 +1,18 @@
 """Segment-reduction message-passing primitives.
 
 JAX has no CSR/CSC sparse (BCOO only), so all graph aggregation in this
-framework is expressed as edge-index gather -> segment reduction, which lowers
-to TPU-friendly dynamic-gather + scatter-add HLO.  These ops ARE the SpMM layer
-of the paper (the GCN convolution ``A_tilde @ X``) and are shared by every GNN
-architecture in ``repro.models.gnn``.
+framework is expressed as edge-index gather -> segment reduction.  The
+``scatter_*`` reductions below lower to dynamic-gather + scatter-add HLO and
+are shared by every GNN architecture in ``repro.models.gnn``.
+
+``spmm`` is the SpMM layer of the paper (the GCN convolution ``A_tilde @ X``).
+A TPU scatter-add costs per element it updates, in whatever order the lanes
+come, so ``spmm`` first sorts its lanes by the row they write and reduces
+contiguous runs.  On a TPU both steps are Pallas kernels of
+``repro.kernels.segment_spmm``: a bitonic sort, whose code is a fraction of
+XLA's sort's (the chip keeps a program's code in HBM), and the one-hot
+reduction.  Elsewhere they are XLA's sort and a sorted ``segment_sum``.  Its
+custom VJP sorts the transpose by source the same way.
 
 Conventions
 -----------
@@ -17,8 +25,14 @@ Conventions
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from repro.kernels.segment_spmm.bitonic import bitonic_sort
+from repro.kernels.segment_spmm.segment_spmm import CHUNK, sorted_segment_sum
+from repro.obs import stages
 
 Array = jax.Array
 
@@ -141,12 +155,93 @@ def gcn_edge_weights(edges: Array, num_nodes: int,
     return w
 
 
+def _sort(key: Array, *payloads: Array) -> tuple[Array, ...]:
+    """Lanes by ascending ``key``: the Pallas bitonic network on a TPU,
+    whose code is a small fraction of XLA's sort's; XLA's sort elsewhere."""
+    return jax.lax.platform_dependent(
+        key, *payloads, tpu=bitonic_sort,
+        default=lambda *a: tuple(jax.lax.sort(a, num_keys=1)))
+
+
+def per_snapshot(fn):
+    """``fn`` over arrays, batched by a loop over the batch rather than by
+    batching rules, so that the Pallas kernels inside always run on one
+    snapshot: their code stays that of the unbatched kernel."""
+    batched = jax.custom_batching.custom_vmap(fn)
+
+    @batched.def_vmap
+    def _loop(axis_size, in_batched, *args):
+        args = [a if b else jnp.broadcast_to(a, (axis_size,) + a.shape)
+                for a, b in zip(args, in_batched, strict=True)]
+        out = jax.lax.map(lambda a: batched(*a), args)
+        return out, jax.tree.map(lambda _: True, out)
+
+    return batched
+
+
+def sorted_lanes(x: Array, frm: Array, to: Array, weights: Array,
+                 num_rows: int) -> tuple[Array, Array]:
+    """Lanes ``weights * x[frm]`` sorted by the row ``to`` they add into.
+
+    Returns the ascending keys (L,) and the messages feature-major (F, L),
+    with the E lanes padded to L, a positive multiple of the kernel's
+    ``CHUNK``.  Zero-weight lanes add nothing: their key is ``num_rows``,
+    so they sort to the end and fall out of range instead of piling onto
+    one row.
+    """
+    e = weights.shape[0]
+    pad = (0, max(-(-e // CHUNK), 1) * CHUNK - e)
+    key = jnp.pad(jnp.where(weights != 0, to, num_rows), pad,
+                  constant_values=num_rows)
+    key, frm, weights = _sort(key, jnp.pad(frm, pad), jnp.pad(weights, pad))
+    msgs = jnp.take(x, frm, axis=0, mode="clip").T * weights.astype(x.dtype)
+    return key, msgs
+
+
+def _sorted_sum(keys: Array, msgs: Array, num_rows: int) -> Array:
+    return jax.ops.segment_sum(msgs.T, keys, num_segments=num_rows,
+                               indices_are_sorted=True)
+
+
+def _aggregate(x: Array, frm: Array, to: Array, weights: Array,
+               num_rows: int) -> Array:
+    """(num_rows, F): row r sums ``weights * x[frm]`` over lanes ``to == r``."""
+    def one(x, frm, to, weights):
+        keys, msgs = sorted_lanes(x, frm, to, weights, num_rows)
+        return jax.lax.platform_dependent(
+            keys, msgs.astype(jnp.float32),
+            tpu=functools.partial(sorted_segment_sum, num_nodes=num_rows),
+            default=functools.partial(_sorted_sum, num_rows=num_rows))
+    return per_snapshot(one)(x, frm, to, weights).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def spmm(x: Array, edges: Array, edge_weights: Array, num_nodes: int) -> Array:
-    """Sparse-dense product ``A_tilde @ x`` via gather + weighted scatter-add.
+    """Sparse-dense product ``A_tilde @ x`` over destination-sorted lanes.
 
     ``edge_weights`` already folds in the Laplacian normalization and the edge
     mask (padded edges carry weight zero), which keeps this inner loop free of
     extra masking work.
     """
-    msgs = gather_src(x, edges) * edge_weights[:, None].astype(x.dtype)
-    return jax.ops.segment_sum(msgs, edges[:, 1], num_segments=num_nodes)
+    with jax.named_scope(stages.SPMM):
+        return _aggregate(x, edges[:, 0], edges[:, 1], edge_weights,
+                          num_nodes)
+
+
+def _spmm_fwd(x, edges, edge_weights, num_nodes):
+    return spmm(x, edges, edge_weights, num_nodes), (x, edges, edge_weights)
+
+
+def _spmm_bwd(_num_nodes, res, g):
+    """``x``'s cotangent is the same sorted reduction with source and
+    destination swapped; the weights' is the row dot ``<x[src], g[dst]>``,
+    which XLA drops where nothing uses it."""
+    x, edges, edge_weights = res
+    with jax.named_scope(stages.SPMM):
+        dx = _aggregate(g, edges[:, 1], edges[:, 0], edge_weights,
+                        x.shape[0])
+        dw = jnp.sum(gather_src(x, edges) * gather_dst(g, edges), axis=-1)
+    return dx, None, dw.astype(edge_weights.dtype)
+
+
+spmm.defvjp(_spmm_fwd, _spmm_bwd)

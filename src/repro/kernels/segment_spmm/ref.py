@@ -6,19 +6,6 @@ import jax
 import jax.numpy as jnp
 
 
-def bucketed_segment_sum_ref(dst_local: jax.Array, messages: jax.Array,
-                             node_block: int) -> jax.Array:
-    """(NB, EPB) x (NB, EPB, F) -> (NB, node_block, F) with segment_sum.
-
-    Padded lanes carry dst_local >= node_block and are dropped (one extra
-    segment, sliced off).
-    """
-    def per_block(dst, msg):
-        out = jax.ops.segment_sum(msg, dst, num_segments=node_block + 1)
-        return out[:node_block]
-    return jax.vmap(per_block)(dst_local, messages)
-
-
 def segment_spmm_ref(x: jax.Array, edges: jax.Array, edge_weights: jax.Array,
                      num_nodes: int) -> jax.Array:
     """End-to-end oracle: A_tilde @ x via plain gather + segment_sum."""

@@ -1,32 +1,38 @@
-"""Pallas TPU kernel: bucketed segment-sum via one-hot MXU matmuls.
+"""Pallas TPU kernel: segment sum over lanes sorted by destination row.
 
 The GCN aggregate ``A_tilde @ X`` is a gather (read x[src]) followed by a
-scatter-add (accumulate into dst).  On GPU the paper uses cuSPARSE SpMM; the
-TPU has no scatter unit, and XLA lowers segment-sum to a serialized
-scatter-add loop.  The TPU-native adaptation: turn the scatter into a
-*one-hot matrix product* so it runs on the MXU systolic array.
+reduction into the destination rows.  XLA lowers that reduction on a TPU
+to a scatter-add whose cost grows with every element it updates, in
+whatever order the lanes come.  Once the lanes are sorted by destination
+(``repro.graph.segment`` sorts them), each block of output rows owns one
+contiguous run of lanes, and the reduction becomes a short series of
+one-hot products on the MXU.
 
-Data layout (produced by ``ops.bucket_edges`` on host / in jnp):
-  * edges sorted by destination and bucketed by destination block:
-    ``dst_local``: (NB, EPB) int32 — dst index *within* its node block;
-    padded lanes carry ``block_size`` (a dump row sliced off after).
-  * ``messages``: (NB, EPB, F) — x[src] * w, gathered OUTSIDE the kernel
-    (XLA's dynamic-gather is already TPU-efficient; the scatter is not).
+Layout:
+  * ``keys``: (E,) int32, ascending; a lane's destination row.  Lanes
+    with a key outside ``[0, num_nodes)`` are left out.
+  * ``msgs``: (F, E) float32, feature-major: one lane per column, so a
+    chunk of lanes is a lane-dense (F, C) tile, F sublanes high.
+  * E is a multiple of ``CHUNK``: the caller pads the lanes before it
+    sorts them, so that no copy of the sorted arrays is made here.
 
-Grid: (NB, F / F_BLK); each step computes
+Grid: one step per (block of ``ROWS`` output rows, chunk of ``CHUNK``
+lanes) pair that overlaps.  A block's lanes ``[bounds[b], bounds[b + 1])``
+come from ``searchsorted`` on the keys; the chunks that hold them are
+visited in consecutive steps, so the block's (F, rows) output tile stays
+in VMEM while each step adds
 
-    out[i, :, fb] = OneHot(dst_local[i])^T @ messages[i, :, fb]
+    out[:, block] += msgs[:, chunk] @ OneHot(keys[chunk] - r0)^T
 
-an (N_BLK x EPB) @ (EPB x F_BLK) MXU matmul.  VMEM working set:
-EPB*F_BLK + EPB*N_BLK + N_BLK*F_BLK floats — all tile-aligned (128 lanes).
-
-``dst_local`` enters the kernel as (NB, 1, EPB) with a (1, 1, EPB) block:
-Mosaic requires a block's last two dims to be (8, 128)-divisible or equal
-to the array's, and a (1, EPB) block of the 2-D array is neither.  The
-one-hot is built directly in its transposed (N_BLK, EPB) form, with the
-destination row broadcast along sublanes, so the contraction is a plain
-matmul.  The dot runs at HIGHEST precision: one-hot weights are exact,
-and the sums must stay f32-exact like the segment-sum oracle's.
+an (F, C) x (C, rows) product on the MXU.  Lanes of a chunk that belong to
+another block match no row of this one.  The schedule (the block and
+chunk of every step) reaches the kernel as scalar prefetch and drives the
+BlockSpecs, so Pallas pipelines the chunks' copies.  A block with no
+lanes still takes one step, which writes its zeros.  The step count is
+bounded by blocks + chunks whatever the degrees: no lane budget, no degree
+bound, and a hub row only makes its block take more steps.  The dot runs
+at HIGHEST precision: one-hot weights are exact, and the sums must stay
+f32-exact like ``jax.ops.segment_sum``'s.
 """
 
 from __future__ import annotations
@@ -36,48 +42,78 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.common import resolve_interpret  # noqa: F401 (re-export)
 
-DEFAULT_NODE_BLOCK = 128
-DEFAULT_FEAT_BLOCK = 128
+ROWS = 256        # output rows per block (lanes of the output tile)
+CHUNK = 512       # lanes per step
 
 
-def _kernel(dst_ref, msg_ref, out_ref, *, node_block: int):
-    # dst_ref: (1, 1, EPB) int32; msg_ref: (1, EPB, FB); out_ref: (1, NB, FB)
-    dst = dst_ref[0]                                   # (1, EPB)
-    msgs = msg_ref[0]                                  # (EPB, FB)
-    # Transposed one-hot over the node block; padded lanes (dst ==
-    # node_block or any value >= node_block) match no row and vanish.
-    rows = jax.lax.broadcasted_iota(jnp.int32, (node_block, dst.shape[1]), 0)
-    onehot_t = (rows == dst).astype(msgs.dtype)        # (NB, EPB)
-    acc = jnp.dot(onehot_t, msgs, precision=jax.lax.Precision.HIGHEST,
-                  preferred_element_type=jnp.float32)
-    out_ref[0] = acc.astype(out_ref.dtype)
+def _kernel(block_ref, chunk_ref, steps_ref, keys_ref, msgs_ref, out_ref, *,
+            rows: int):
+    # keys_ref: (1, C) int32; msgs_ref: (F, C); out_ref: (F, rows)
+    i = pl.program_id(0)
+    b = block_ref[i]
+
+    @pl.when((i == 0) | (block_ref[jnp.maximum(i - 1, 0)] != b))
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+    @pl.when(i < steps_ref[0])
+    def _():
+        row_ids = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, keys_ref.shape[1]), 0) + b * rows
+        onehot = (row_ids == keys_ref[...]).astype(jnp.float32)
+        out_ref[...] += jax.lax.dot_general(
+            msgs_ref[...], onehot, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("node_block", "feat_block",
-                                             "interpret"))
-def bucketed_segment_sum(dst_local: jax.Array, messages: jax.Array,
-                         node_block: int = DEFAULT_NODE_BLOCK,
-                         feat_block: int = DEFAULT_FEAT_BLOCK,
-                         interpret: bool | None = None) -> jax.Array:
-    """(NB, EPB) int32 x (NB, EPB, F) -> (NB, node_block, F)."""
-    interpret = resolve_interpret(interpret)
-    nb, epb = dst_local.shape
-    f = messages.shape[-1]
-    if f % feat_block != 0:
-        raise ValueError(f"F={f} must be a multiple of feat_block={feat_block}")
-    grid = (nb, f // feat_block)
-    return pl.pallas_call(
-        functools.partial(_kernel, node_block=node_block),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, epb), lambda i, _j: (i, 0, 0)),
-            pl.BlockSpec((1, epb, feat_block), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, node_block, feat_block),
-                               lambda i, j: (i, 0, j)),
-        out_shape=jax.ShapeDtypeStruct((nb, node_block, f), messages.dtype),
+def _schedule(keys: jax.Array, num_nodes: int, rows: int, chunk: int):
+    """(block, chunk) of every grid step, and the number of live steps.
+
+    Steps past the live ones repeat the last live step's indices, so they
+    copy nothing and, skipped by the kernel, add nothing."""
+    nb = -(-num_nodes // rows)
+    nc = keys.shape[0] // chunk
+    # lane offsets of each block; keys >= num_nodes fall past the last one
+    bounds = jnp.searchsorted(
+        keys, jnp.minimum(jnp.arange(nb + 1) * rows, num_nodes)
+    ).astype(jnp.int32)
+    first = jnp.minimum(bounds[:-1] // chunk, nc - 1)
+    last = jnp.maximum(jnp.minimum(-(-bounds[1:] // chunk), nc), first + 1)
+    ends = jnp.cumsum(last - first)
+    step = jnp.arange(nb + nc, dtype=jnp.int32)
+    block = jnp.minimum(jnp.searchsorted(ends, step, side="right"), nb - 1)
+    at = first[block] + step - (ends[block] - (last - first)[block])
+    return (block.astype(jnp.int32),
+            jnp.minimum(at, last[block] - 1).astype(jnp.int32), ends[-1:])
+
+
+@functools.partial(jax.jit, static_argnames=("num_nodes", "interpret"))
+def sorted_segment_sum(keys: jax.Array, msgs: jax.Array, num_nodes: int,
+                       interpret: bool = False) -> jax.Array:
+    """(E,) ascending int32 keys x (F, E) f32 messages -> (num_nodes, F).
+
+    Equals ``jax.ops.segment_sum(msgs.T, keys, num_nodes)`` up to the
+    order of the f32 additions.  E must be a multiple of ``CHUNK``.
+    """
+    f, e = msgs.shape
+    if e % CHUNK:
+        raise ValueError(f"{e} lanes are not a multiple of CHUNK={CHUNK}")
+    nb = -(-num_nodes // ROWS)
+    block, at, steps = _schedule(keys, num_nodes, ROWS, CHUNK)
+    out = pl.pallas_call(
+        functools.partial(_kernel, rows=ROWS),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(block.shape[0],),
+            in_specs=[pl.BlockSpec((1, CHUNK), lambda i, b, c, n: (0, c[i])),
+                      pl.BlockSpec((f, CHUNK), lambda i, b, c, n: (0, c[i]))],
+            out_specs=pl.BlockSpec((f, ROWS), lambda i, b, c, n: (0, b[i]))),
+        out_shape=jax.ShapeDtypeStruct((f, nb * ROWS), jnp.float32),
         interpret=interpret,
-    )(dst_local.reshape(nb, 1, epb), messages)
+    )(block, at, steps, keys[None], msgs)
+    return out[:, :num_nodes].T

@@ -1,7 +1,7 @@
 """Per-kernel allclose sweeps against the pure-jnp oracles (interpret mode).
 
 Covers shapes x dtypes for all three Pallas kernels + hypothesis property
-tests on the bucketed segment-sum layout.
+tests on the destination-sorted segment-sum layout.
 """
 
 import jax
@@ -15,12 +15,21 @@ pytest.importorskip(
 from hypothesis import given, settings  # noqa: E402
 from hypothesis import strategies as st  # noqa: E402
 
+from repro.graph import segment
 from repro.kernels.flash_decode import ops as fd_ops
 from repro.kernels.mproduct import ops as mp_ops
-from repro.kernels.segment_spmm import ops as spmm_ops
+from repro.kernels.segment_spmm.ref import segment_spmm_ref
+from repro.kernels.segment_spmm.segment_spmm import sorted_segment_sum
 
 
 # ------------------------------------------------------- segment_spmm ------
+
+def _kernel_spmm(x, edges, w, n):
+    """``A_tilde @ x`` as a TPU runs it, the kernel interpreted: the lanes
+    of ``segment.spmm`` sorted by destination, then the Pallas reduction."""
+    keys, msgs = segment.sorted_lanes(x, edges[:, 0], edges[:, 1], w, n)
+    return sorted_segment_sum(keys, msgs, n, interpret=True)
+
 
 @pytest.mark.parametrize("n,e,f", [(200, 1000, 64), (300, 2000, 100),
                                    (128, 500, 128), (64, 64, 32),
@@ -31,10 +40,10 @@ def test_segment_spmm_matches_oracle(n, e, f, dtype):
     edges = rng.integers(0, n, size=(e, 2)).astype(np.int32)
     w = rng.normal(size=(e,)).astype(dtype)
     x = rng.normal(size=(n, f)).astype(dtype)
-    got = spmm_ops.segment_spmm(jnp.asarray(x), jnp.asarray(edges),
-                                jnp.asarray(w), n)
-    want = spmm_ops.segment_spmm_ref(jnp.asarray(x), jnp.asarray(edges),
-                                     jnp.asarray(w), n)
+    got = _kernel_spmm(jnp.asarray(x), jnp.asarray(edges),
+                       jnp.asarray(w), n)
+    want = segment_spmm_ref(jnp.asarray(x), jnp.asarray(edges),
+                            jnp.asarray(w), n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -46,9 +55,9 @@ def test_segment_spmm_masked_edges_ignored():
     w = rng.normal(size=(e,)).astype(np.float32)
     w[e // 2:] = 0.0   # padded lanes carry zero weight
     x = rng.normal(size=(n, f)).astype(np.float32)
-    got = spmm_ops.segment_spmm(jnp.asarray(x), jnp.asarray(edges),
-                                jnp.asarray(w), n)
-    want = spmm_ops.segment_spmm_ref(
+    got = _kernel_spmm(jnp.asarray(x), jnp.asarray(edges),
+                       jnp.asarray(w), n)
+    want = segment_spmm_ref(
         jnp.asarray(x[:, :f]), jnp.asarray(edges[:e // 2]),
         jnp.asarray(w[:e // 2]), n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -63,10 +72,10 @@ def test_segment_spmm_property(n, e, f, seed):
     edges = rng.integers(0, n, size=(e, 2)).astype(np.int32)
     w = rng.normal(size=(e,)).astype(np.float32)
     x = rng.normal(size=(n, f)).astype(np.float32)
-    got = spmm_ops.segment_spmm(jnp.asarray(x), jnp.asarray(edges),
-                                jnp.asarray(w), n)
-    want = spmm_ops.segment_spmm_ref(jnp.asarray(x), jnp.asarray(edges),
-                                     jnp.asarray(w), n)
+    got = _kernel_spmm(jnp.asarray(x), jnp.asarray(edges),
+                       jnp.asarray(w), n)
+    want = segment_spmm_ref(jnp.asarray(x), jnp.asarray(edges),
+                            jnp.asarray(w), n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 

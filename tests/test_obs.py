@@ -362,8 +362,9 @@ def _op_names(hlo: str) -> list[str]:
 ])
 def test_snapshot_step_ops_carry_their_stage(model, expected):
     """Every stage of the model names device ops of the compiled
-    per-snapshot step, and the backward ops of the spatial stage sit
-    under ``transpose(jvp(spatial))``."""
+    per-snapshot step, the backward ops of the spatial stage sit under
+    ``transpose(jvp(spatial))``, and the sorted aggregation's own ops, in
+    its forward and its custom backward rule, sit under ``spmm``."""
     import jax.numpy as jnp
     from repro.core import models as mdl
     from repro.optim import adamw
@@ -384,6 +385,17 @@ def test_snapshot_step_ops_carry_their_stage(model, expected):
     assert any("transpose(jvp(spatial))" in name for name in names)
     if model != "evolvegcn":
         assert not {"a2a", "delta_apply"} & seen
+    # the sorted aggregation: its lane sort (and the sort's comparator)
+    # and the reduction behind its platform switch, in the forward and in
+    # the custom backward rule, all sit under spmm (names outside jit(step)
+    # belong to sub-computations, such as the sort's comparator, not ops)
+    agg = [n for n in names if n.startswith("jit(") and (
+        n.rsplit("/", 1)[-1] in ("sort", "lt_to") or "/cond/" in n)]
+    assert any("transpose(" in n for n in agg), agg
+    assert any("transpose(" not in n for n in agg), agg
+    for name in agg:
+        assert _stages(name)[-1:] == ["spmm"], name
+        assert "edge_weights" not in _stages(name), name
 
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 host devices")

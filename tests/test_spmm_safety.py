@@ -1,31 +1,29 @@
-"""Regression tests for the segment_spmm safety fixes (PR 2 satellites).
+"""Regression tests for the segment_spmm interpret resolution and grid.
 
-No hypothesis dependency (unlike test_kernels.py) so these always run:
-* interpret resolution — the "Pallas" path must never silently interpret on
-  a real accelerator backend, and must interpret on CPU;
-* bucketing overflow — tight edges_per_block budgets are detected (via
-  checkify) and recoverable (dense fallback), never silently wrong.
+No hypothesis dependency (unlike test_kernels.py) so these always run: the
+"Pallas" path must never silently interpret on a real accelerator backend,
+and must interpret on CPU; the reduction's grid covers every lane once
+whatever the degrees.
 """
-
-import warnings
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import checkify
 
-from repro.core import gcn as gcnlib
+from repro.graph import segment
 from repro.kernels.common import resolve_interpret
-from repro.kernels.segment_spmm import ops as spmm_ops
+from repro.kernels.segment_spmm.ref import segment_spmm_ref
+from repro.kernels.segment_spmm.segment_spmm import (CHUNK, ROWS, _schedule,
+                                                     sorted_segment_sum)
 
 N, E, F = 192, 800, 64
 
 
-def _graph(seed=0, skewed=False):
+def _graph(seed=0):
     rng = np.random.default_rng(seed)
     src = rng.integers(0, N, size=(E,))
-    dst = np.zeros((E,), np.int64) if skewed else rng.integers(0, N, (E,))
+    dst = rng.integers(0, N, (E,))
     edges = np.stack([src, dst], axis=1).astype(np.int32)
     w = rng.normal(size=(E,)).astype(np.float32)
     x = rng.normal(size=(N, F)).astype(np.float32)
@@ -46,90 +44,46 @@ def test_interpret_resolves_from_backend(monkeypatch):
 
 
 def test_segment_spmm_default_interpret_runs_on_cpu():
-    """The default (interpret=None) path must work on the CPU backend and
-    match the oracle — i.e. resolution actually reaches pallas_call."""
+    """The kernel in the mode ``resolve_interpret(None)`` picks on the CPU
+    backend runs there and matches the oracle, i.e. the resolution
+    actually reaches pallas_call."""
     assert jax.default_backend() == "cpu"
     x, edges, w = _graph()
-    got = spmm_ops.segment_spmm(x, edges, w, N)
-    want = spmm_ops.segment_spmm_ref(x, edges, w, N)
+    keys, msgs = segment.sorted_lanes(x, edges[:, 0], edges[:, 1], w, N)
+    got = sorted_segment_sum(keys, msgs, N,
+                             interpret=resolve_interpret(None))
+    want = segment_spmm_ref(x, edges, w, N)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
 
-def test_spatial_aggregate_threads_interpret():
-    """core.gcn.spatial_aggregate forwards the flag to the kernel wrapper."""
-    x, edges, w = _graph(seed=1)
-    got = gcnlib.spatial_aggregate(x, edges, w, N, use_pallas=True,
-                                   interpret=True)
-    want = gcnlib.spatial_aggregate(x, edges, w, N, use_pallas=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=1e-4, atol=1e-4)
-
-
-# --------------------------------------------------- bucketing overflow ----
-
-def test_overflow_count_zero_for_default_budget():
-    x, edges, w = _graph(seed=2, skewed=True)
-    cnt = spmm_ops.bucket_overflow_count(edges, w, N, jnp.int32(E))
-    assert int(cnt) == 0
-
-
-def test_overflow_count_ignores_zero_weight_padding():
-    """Padded lanes (weight 0) beyond the budget are a lossless drop."""
-    x, edges, w = _graph(seed=3, skewed=True)
-    cnt_real = int(spmm_ops.bucket_overflow_count(edges, w, N,
-                                                  jnp.int32(128)))
-    cnt_pad = int(spmm_ops.bucket_overflow_count(edges, jnp.zeros_like(w),
-                                                 N, jnp.int32(128)))
-    assert cnt_real > 0
-    assert cnt_pad == 0
-
-
-def test_tight_budget_overflow_surfaces_via_checkify():
-    """A skewed destination distribution with a stats-sized budget raises
-    under checkify instead of silently dropping edges."""
-    x, edges, w = _graph(seed=4, skewed=True)
-    fn = checkify.checkify(
-        lambda xx, ee, ww: spmm_ops.segment_spmm(xx, ee, ww, N,
-                                                 edges_per_block=128),
-        errors=checkify.user_checks)
-    err, _ = fn(x, edges, w)
-    with pytest.raises(checkify.JaxRuntimeError,
-                       match="overflow edges_per_block"):
-        err.throw()
-    # the safe default budget passes the same check
-    fn_ok = checkify.checkify(
-        lambda xx, ee, ww: spmm_ops.segment_spmm(xx, ee, ww, N),
-        errors=checkify.user_checks)
-    err_ok, out = fn_ok(x, edges, w)
-    err_ok.throw()
-    np.testing.assert_allclose(
-        np.asarray(out), np.asarray(spmm_ops.segment_spmm_ref(x, edges, w,
-                                                              N)),
-        rtol=1e-4, atol=1e-4)
-
-
-def test_checked_wrapper_falls_back_dense_on_overflow():
-    x, edges, w = _graph(seed=5, skewed=True)
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        got = spmm_ops.segment_spmm_checked(x, edges, w, N,
-                                            edges_per_block=128)
-    assert any("falling back" in str(r.message) for r in rec)
-    np.testing.assert_allclose(
-        np.asarray(got),
-        np.asarray(spmm_ops.segment_spmm_ref(x, edges, w, N)),
-        rtol=1e-4, atol=1e-4)
-
-
-def test_checked_wrapper_stays_on_kernel_when_budget_fits():
-    x, edges, w = _graph(seed=6, skewed=False)
-    with warnings.catch_warnings(record=True) as rec:
-        warnings.simplefilter("always")
-        got = spmm_ops.segment_spmm_checked(x, edges, w, N,
-                                            edges_per_block=E)
-    assert not any("falling back" in str(r.message) for r in rec)
-    np.testing.assert_allclose(
-        np.asarray(got),
-        np.asarray(spmm_ops.segment_spmm_ref(x, edges, w, N)),
-        rtol=1e-4, atol=1e-4)
+@pytest.mark.parametrize("hub", [False, True])
+def test_schedule_visits_each_block_and_its_chunks_once(hub):
+    """The reduction's grid: every block of ROWS output rows, in order,
+    takes the chunks of CHUNK lanes that hold its lanes, each once (and
+    one step if it has none), in at most blocks + chunks steps whatever
+    the degrees; the steps past the live ones repeat the last one."""
+    n = 20 * ROWS + 17
+    rng = np.random.default_rng(7)
+    # blocks 5-7 have no lanes, and block 8's start on a chunk boundary;
+    # zero-weight lanes sort to the end with key n, out of range
+    dst = np.concatenate([rng.integers(0, 5 * ROWS, 10 * CHUNK),
+                          rng.integers(8 * ROWS, n, 26 * CHUNK),
+                          np.full(4 * CHUNK, n)])
+    if hub:
+        dst[: 25 * CHUNK] = 3 * ROWS + 5
+    keys = np.sort(dst).astype(np.int32)
+    block, chunk, steps = (np.asarray(a) for a in _schedule(
+        jnp.asarray(keys), n, ROWS, CHUNK))
+    nb, nc, live = -(-n // ROWS), keys.shape[0] // CHUNK, int(steps[0])
+    assert live <= nb + nc == block.shape[0]
+    want = []
+    for b in range(nb):
+        lo, hi = np.searchsorted(keys, [b * ROWS, min((b + 1) * ROWS, n)])
+        if lo == hi:
+            want.append((b, min(lo // CHUNK, nc - 1)))
+        else:
+            want += [(b, c) for c in range(lo // CHUNK, -(-hi // CHUNK))]
+    assert list(zip(block[:live].tolist(), chunk[:live].tolist())) == want
+    assert set(block[live:].tolist()) <= {block[live - 1]}
+    assert set(chunk[live:].tolist()) <= {chunk[live - 1]}

@@ -3,8 +3,9 @@
 The TPU compiler refuses what interpret mode accepts: block shapes that
 break Mosaic's tiling rules, and programs that do not fit the chip's
 16 GB.  These tests compile the two Pallas kernels at the widths the main
-path uses and the streamed train step at the ``dtdg_epinions`` snapshot
-shape, so such a regression fails here instead of on the chip.
+path uses, the sorted aggregation, and the streamed train step at the
+``dtdg_epinions`` snapshot shape, so such a regression fails here instead
+of on the chip.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and only the worker that runs this
@@ -20,9 +21,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import registry
 from repro.core import models as mdl
+from repro.graph import segment
 from repro.kernels.mproduct.mproduct import banded_ttm
-from repro.kernels.segment_spmm import ops as spmm_ops
-from repro.kernels.segment_spmm.segment_spmm import bucketed_segment_sum
+from repro.kernels.segment_spmm.segment_spmm import sorted_segment_sum
 from repro.optim import adamw
 from repro.stream import train_loop
 
@@ -34,7 +35,9 @@ N = CFG.num_nodes
 # the 32-step seeded trace (2,098,287) plus N self-loop lanes, rounded
 # to 128
 E_LANES = 2_853_504
-SPMM_NODES = 65_536                     # segment_spmm pads F to 128 lanes
+# lanes of one snapshot's aggregation in the benchmark's epinions cell:
+# 2,852,480 edge-buffer lanes plus N self-loops
+AGG_LANES = 3_607_680
 
 
 @pytest.fixture(scope="module")
@@ -76,29 +79,44 @@ def test_banded_ttm_compiles_at_tmgcn_width(one_chip):
     _fits(compiled)
 
 
-@pytest.mark.parametrize("epb", [128, 4096])
-def test_bucketed_segment_sum_compiles(one_chip, epb):
-    """The kernel alone: node_block 128, feat_block 128."""
-    nb = SPMM_NODES // 128
-    compiled = jax.jit(lambda d, m: bucketed_segment_sum(
-        d, m, node_block=128, feat_block=128, interpret=False)).lower(
-        _sds((nb, epb), jnp.int32, one_chip),
-        _sds((nb, epb, 128), jnp.float32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-    _fits(compiled)
-
-
 def test_segment_spmm_compiles_with_bucketing(one_chip):
-    """The wrapper users call: jnp bucketing + gather + the kernel, at the
-    model's width F=6 and the trace's ~2.78 edges per vertex."""
-    e = int(SPMM_NODES * 2.78)
-    compiled = jax.jit(lambda x, ed, w: spmm_ops.segment_spmm(
-        x, ed, w, SPMM_NODES, edges_per_block=4096, interpret=False)).lower(
-        _sds((SPMM_NODES, 6), jnp.float32, one_chip),
-        _sds((e, 2), jnp.int32, one_chip),
-        _sds((e,), jnp.float32, one_chip)).compile()
+    """The kernel over its destination-sorted lanes (the sort and the
+    gather of ``segment.sorted_lanes``), at the model's width F=6 and the
+    epinions snapshot shape."""
+    def kernel_spmm(x, edges, w):
+        keys, msgs = segment.sorted_lanes(x, edges[:, 0], edges[:, 1], w, N)
+        return sorted_segment_sum(keys, msgs, N, interpret=False)
+
+    compiled = jax.jit(kernel_spmm).lower(
+        _sds((N, CFG.hidden), jnp.float32, one_chip),
+        _sds((AGG_LANES, 2), jnp.int32, one_chip),
+        _sds((AGG_LANES,), jnp.float32, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     _fits(compiled)
+
+
+def test_sorted_aggregation_needs_no_more_memory_than_random_order(
+        one_chip):
+    """``segment.spmm`` (sort, gather, the Pallas reduction) at the
+    epinions cell's shape takes at most 1% more temporary HBM than a
+    plain gather + random-order ``segment_sum`` of the same lanes.  Its
+    sort is the Pallas network, not XLA's: the chip keeps a program's
+    code in HBM, and XLA's sort of these lanes is megabytes of it."""
+    args = (_sds((N, CFG.hidden), jnp.float32, one_chip),
+            _sds((AGG_LANES, 2), jnp.int32, one_chip),
+            _sds((AGG_LANES,), jnp.float32, one_chip))
+
+    def random_order(x, edges, w):
+        msgs = jnp.take(x, edges[:, 0], axis=0) * w[:, None]
+        return jax.ops.segment_sum(msgs, edges[:, 1], num_segments=N)
+
+    sorted_ = jax.jit(lambda x, ed, w: segment.spmm(x, ed, w, N)
+                      ).lower(*args).compile()
+    plain = jax.jit(random_order).lower(*args).compile()
+    hlo = sorted_.as_text()
+    assert "tpu_custom_call" in hlo and " sort(" not in hlo
+    temp = sorted_.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.01 * plain.memory_analysis().temp_size_in_bytes
 
 
 def test_stream_train_step_fits_one_chip(one_chip):
