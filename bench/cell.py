@@ -61,7 +61,10 @@ class Cell:
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
-    """The cell named ``workload`` in ``root/BENCHMARK.json``."""
+    """The cell named ``workload`` in ``root/BENCHMARK.json``.  Exits,
+    naming what is missing, where this tree lacks a file the cell needs:
+    its configuration, its traffic, its reference model or a reader of
+    one of its per-layer metrics."""
     manifest = json.loads((root / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in manifest["workloads"]}
     if workload not in cells:
@@ -73,13 +76,25 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     def mine(m):
         return "workloads" not in m or workload in m["workloads"]
 
+    per_layer = [m for m in manifest["per_layer"] if mine(m)]
+    config = root / entry["file"]
+    traffic = HERE / "traffic" / f"{w['traffic']}.json"
+    needed = [config, traffic] + [HERE / "metrics" / f"{m['name']}.py"
+                                  for m in per_layer]
+    if config.exists():
+        model = json.loads(config.read_text())["model"]
+        needed.append(reference.MODELS / f"{model}.py")
+    missing = [str(p.relative_to(ROOT) if p.is_relative_to(ROOT) else p)
+               for p in needed if not p.exists()]
+    if missing:
+        raise SystemExit(f"workload {workload!r} cannot run on this tree: "
+                         f"missing {', '.join(missing)}")
     return Cell(
         name=workload, chips=w["chips"],
-        config=json.loads((root / entry["file"]).read_text()),
-        traffic=json.loads(
-            (HERE / "traffic" / f"{w['traffic']}.json").read_text()),
+        config=json.loads(config.read_text()),
+        traffic=json.loads(traffic.read_text()),
         end_to_end=[m for m in manifest["end_to_end"] if mine(m)],
-        per_layer=[m for m in manifest["per_layer"] if mine(m)])
+        per_layer=per_layer)
 
 
 def load_reader(metric: str):
@@ -331,7 +346,7 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, *,
     else:
         import trace_reduce
         red = trace_reduce.reduce_dir(
-            trace_dir, chips=cell.chips,
+            trace_dir, chips=cell.chips, epochs=window["epochs"],
             rounds=window["epochs"] * cell.steps_per_epoch)
         shape = shape_of(cell)
         mod = reference.model(c["model"])

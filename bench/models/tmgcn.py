@@ -102,3 +102,4 @@ def slice_loss(params: dict, carries: list, frames, labels, edges, weights,
 
 flops_per_snapshot = work.flops_per_snapshot
 bytes_per_snapshot = work.bytes_per_snapshot
+spmm_per_snapshot = work.spmm_per_snapshot

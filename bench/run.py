@@ -73,12 +73,14 @@ def main(argv=None) -> None:
     # the persistent compile cache lives at a fixed path in the checkout
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     sys.path.insert(0, str(ROOT / "src"))
+    import cell as cell_mod
+
+    # a cell this tree cannot run fails here, before the chip is touched
+    cell = cell_mod.load_cell(args.workload)
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    import cell as cell_mod
     from repro.launch.compile_cache import enable_compile_cache
 
-    cell = cell_mod.load_cell(args.workload)
     devices = accelerator(cell.chips)
     peaks = load_peaks(devices[0].device_kind)
     log(f"jax {jax.__version__}, {len(devices)} x {devices[0].device_kind}"

@@ -46,7 +46,6 @@ import sys
 from dataclasses import dataclass, field
 from pathlib import Path
 
-import work
 from trace_reduce import DEVICE_PLANE, HOST_PLANE, OP_LINE, WINDOW_MARK, gaps
 
 METADATA_PLANE = "/host:metadata"
@@ -432,22 +431,6 @@ def reduce_dir(trace_dir: str, *, chips: int) -> Stages:
         raise RuntimeError(f"expected one .xplane.pb under {trace_dir}, "
                            f"found {len(paths)}")
     return reduce(read(paths[0]), chips=chips)
-
-
-def spmm_per_snapshot(s: work.Shape) -> dict[str, int]:
-    """FLOPs and least HBM bytes of one snapshot's aggregation passes
-    alone (``A_tilde @ X``: every layer's forward and every layer's
-    transpose but the first's), by ``work.py``'s rules: a multiply and
-    an add per lane and feature; per pass the edge list and its weights
-    read, one source row gathered per lane at its own width, and the
-    (N, d) result written."""
-    n, e = s.num_nodes, s.lanes
-    passes = [d_in for layer, (d_in, _) in enumerate(s.widths())
-              for _ in range(2 if layer else 1)]
-    flops = sum(2 * e * d for d in passes)
-    nbytes = sum((2 * work.INDEX + work.F32) * e + work.F32 * d * e
-                 + work.F32 * n * d for d in passes)
-    return {"flops": flops, "bytes": nbytes}
 
 
 # ---------------------------------------------------------------- readers ---

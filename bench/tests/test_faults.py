@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tiny import make_root, run_cell
+from tiny import CELLS, make_root, run_cell
 
 SEED = 2**31 + 23
 
@@ -64,8 +64,8 @@ def _altered_loss(mp):
 
 FAULTS = {"unchanged_state": _unchanged_state, "half_batch": _half_batch,
           "altered_loss": _altered_loss, "no_exchange": _no_exchange}
-CASES = [(w, f) for w in ("tmgcn-epinions-1chip", "tmgcn-epinions-4chip")
-         for f in FAULTS if f != "no_exchange" or w.endswith("4chip")]
+CASES = [(w, f) for w in sorted(CELLS)
+         for f in FAULTS if f != "no_exchange" or CELLS[w]["chips"] > 1]
 
 
 @pytest.mark.parametrize("workload,fault", CASES)
@@ -79,5 +79,6 @@ def test_fault_is_not_correct(root, monkeypatch, workload, fault):
 
 
 def test_sound_program_is_correct(root):
+    mesh = next(n for n in sorted(CELLS) if CELLS[n]["chips"] > 1)
     jax.clear_caches()
-    assert run_cell(root, "tmgcn-epinions-4chip", SEED)["correct"] is True
+    assert run_cell(root, mesh, SEED)["correct"] is True

@@ -12,7 +12,7 @@ import pytest
 
 import cell as cell_mod
 import run
-from conftest import BENCH, ROOT
+from tiny import BENCH, ROOT
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -126,3 +126,41 @@ def test_every_cell_reports_setup_another_metric_and_a_layer():
 def test_unknown_workload_is_refused():
     with pytest.raises(SystemExit, match="unknown workload"):
         cell_mod.load_cell("no-such-cell")
+
+
+def _tree(tmp_path, model="tmgcn", reader=None, traffic=None):
+    """A manifest of the one-chip cell whose configuration names
+    ``model``, which declares the per-layer metric ``reader`` too, and
+    which runs ``traffic`` where given."""
+    manifest = json.loads(json.dumps(MANIFEST))
+    if traffic:
+        manifest["workloads"][0]["traffic"] = traffic
+    cfg = json.loads((ROOT / "bench/configs/tmgcn-epinions.json").read_text())
+    (tmp_path / "cfg.json").write_text(json.dumps({**cfg, "model": model}))
+    manifest["configs"] = [{**manifest["configs"][0], "file": "cfg.json"}]
+    if reader:
+        manifest["per_layer"].append({**manifest["per_layer"][0],
+                                      "name": reader})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(manifest))
+    return tmp_path
+
+
+@pytest.mark.parametrize("model,reader,traffic,missing", [
+    ("nosuchmodel", None, None, "bench/models/nosuchmodel.py"),
+    ("tmgcn", "train.nosuch_ms", None, "bench/metrics/train.nosuch_ms.py"),
+    ("tmgcn", None, "nosuch-mix", "bench/traffic/nosuch-mix.json")])
+def test_a_cell_this_tree_cannot_run_exits_before_the_device(
+        tmp_path, monkeypatch, model, reader, traffic, missing):
+    import jax
+
+    def no_device(*_a, **_k):
+        raise AssertionError("load_cell asked JAX for a device")
+
+    monkeypatch.setattr(jax, "devices", no_device)
+    workload = MANIFEST["workloads"][0]["name"]
+    root = _tree(tmp_path, model, reader, traffic)
+    with pytest.raises(SystemExit, match=re.escape(missing)) as exc:
+        cell_mod.load_cell(workload, root)
+    assert "\n" not in str(exc.value)
+    assert cell_mod.load_cell(workload, _tree(tmp_path)).config["model"] \
+        == "tmgcn"

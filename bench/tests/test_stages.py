@@ -13,7 +13,7 @@ import cell as cell_mod
 import stages
 import trace_reduce
 import work
-from conftest import BENCH
+from tiny import BENCH
 
 SCOPED = BENCH / "tests" / "fixtures" / "trace_2chips_scoped.pbtxt"
 PLAIN = BENCH / "tests" / "fixtures" / "trace_2chips.pbtxt"
@@ -65,7 +65,7 @@ def test_ops_go_to_their_innermost_stage_and_partition_the_op_time():
     # every module the window runs, as the step time reads it
     assert red.op_s == pytest.approx(sum(red.stage_s.values()))
     assert red.op_s == pytest.approx(trace_reduce.reduce(
-        _profile(SCOPED), chips=2, rounds=2).step_device_s)
+        _profile(SCOPED), chips=2, epochs=2, rounds=2).step_device_s)
     assert red.seconds("spatial", "spmm") == pytest.approx(7_500 * NS)
     assert red.device_ops[0] == ["spmm/fwd · fusion.1",
                                  pytest.approx(6_000 * NS)]
@@ -165,9 +165,10 @@ def _context(path, snapshots=2):
         cell=cell, peaks={"bf16_flops_per_s": 197e12,
                           "hbm_bytes_per_s": 819e9},
         setup={}, window={"snapshots": snapshots, "seconds": 1.0,
-                          "epochs": 1},
+                          "epochs": 2},
         spans={}, transfer_bytes_per_epoch=0.0,
-        trace=trace_reduce.reduce(_profile(path), chips=2, rounds=2))
+        trace=trace_reduce.reduce(_profile(path), chips=2, epochs=2,
+                                  rounds=2))
     ctx.stages = stages.reduce(stages.from_text(path.read_text()), chips=2)
     return ctx
 
@@ -187,7 +188,7 @@ def test_readers_on_a_scoped_trace():
         100 * 1_200 / 5_200)
     # a2a: 1000 ns over 2 chips and 2 rounds, the exposed reader's base
     assert read["train.a2a_ms"] == pytest.approx(1e3 * 1_000 * NS / 4)
-    least = stages.spmm_per_snapshot(cell_mod.shape_of(ctx.cell))
+    least = work.spmm_per_snapshot(cell_mod.shape_of(ctx.cell))
     assert read["train.spmm_roofline"] == pytest.approx(
         100 * max(least["flops"] / 197e12, least["bytes"] / 819e9)
         / (7_000 * NS / 2))
@@ -254,7 +255,7 @@ def test_spmm_per_snapshot_against_a_hand_count():
     # layer 1 forward (d=2), layer 2 forward and transpose (d=3, d=3)
     s = work.Shape(num_nodes=10, num_edges=20, feat_in=2, hidden=3,
                    out_dim=4, num_layers=2, window=5, num_classes=2)
-    got = stages.spmm_per_snapshot(s)
+    got = work.spmm_per_snapshot(s)
     assert got["flops"] == 2 * 30 * (2 + 3 + 3)
     # per pass: 12 B of edge list and weight per lane, 4*d B gathered per
     # lane, 4*d B per vertex written
@@ -264,7 +265,7 @@ def test_spmm_per_snapshot_against_a_hand_count():
 
 def test_spmm_work_at_the_epinions_shape():
     s = cell_mod.shape_of(cell_mod.load_cell("tmgcn-epinions-1chip"))
-    got = stages.spmm_per_snapshot(s)
+    got = work.spmm_per_snapshot(s)
     assert got["flops"] == 79_865_912                   # 79.9 MFLOP
     assert got["bytes"] == 304_707_768                  # 304.7 MB
     assert got["bytes"] / 819e9 == pytest.approx(0.372e-3, rel=1e-3)

@@ -67,3 +67,11 @@ def test_alltoall_bytes_match_program_model():
     for chips in (1, 2, 4):
         assert work.alltoall_bytes_per_round(8, 1024, 6, 2, chips) \
             == alltoall_round_payload(8, 1024, 6, 2, chips)
+
+
+def test_spmm_per_snapshot_at_the_tiny_shape():
+    """The aggregation's count as ``bench/stages.py`` gave it before it
+    moved here: passes at widths 1, 2 and 2 over 7 lanes."""
+    assert work.spmm_per_snapshot(TINY) == {"flops": 70, "bytes": 472}
+    assert reference.model("tmgcn").spmm_per_snapshot is \
+        work.spmm_per_snapshot

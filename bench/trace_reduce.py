@@ -22,11 +22,25 @@ module to another.  Every module must be named in ``STEP_MODULES`` or
 ``EPOCH_MODULES``, and an epoch module may run only before its epoch's
 first train step: anything else fails the reduction, so that no share
 is ever taken of a step whose structure the lists do not describe.
+
+Modules go to epochs by their order on each chip, not by the host's
+marks: the device's clock reads ahead of the host's by up to a
+millisecond, more as the window goes on, so an epoch's parameter draw
+can start on the trace before the mark the host opened before drawing
+it.  On each chip the first epoch opens at the window's start, and an
+epoch module that follows a train step opens the next.  Every chip must
+run the window's ``rounds`` train steps, and a chip that runs epoch
+modules must split them into the window's ``epochs``, each holding the
+same number: an epoch module between two steps of one epoch makes an
+epoch too many.  The window must hold one ``bench.epoch`` mark for each
+of its ``epochs``.  What the order cannot tell: an epoch module that runs
+between the last step of one epoch and the first of the next, where the
+next epoch's draw runs, is taken for part of that draw.  Its time still
+counts in the step time; only its name goes unchecked.
 """
 
 from __future__ import annotations
 
-import bisect
 import glob
 import re
 from dataclasses import dataclass, field
@@ -49,14 +63,16 @@ STEP_MODULES = frozenset({
     TRAIN_STEP, "jit_apply_delta", "jit_copy", "jit_concatenate",
     "jit_broadcast_in_dim", "jit_convert_element_type"})
 # before each epoch's first train step: the parameters drawn from the seed
+# (EvolveGCN's also fold a key in)
 EPOCH_MODULES = frozenset({
-    "jit__threefry_seed", "jit__threefry_split", "jit__uniform",
-    "jit__unstack", "jit__multi_slice", "jit_negative", "jit_sqrt",
-    "jit_true_divide"})
+    "jit__threefry_seed", "jit__threefry_split", "jit__threefry_fold_in",
+    "jit__uniform", "jit__unstack", "jit__multi_slice", "jit_negative",
+    "jit_sqrt", "jit_true_divide"})
 
 class UnknownModule(RuntimeError):
-    """The window ran a device program that neither list names, or an
-    epoch's set-up program after that epoch's first train step."""
+    """The window ran a device program that neither list names, an
+    epoch's set-up program after that epoch's first train step, or not
+    the window's train steps."""
 
 
 def module_name(event_name: str) -> str:
@@ -122,8 +138,9 @@ class Reduction:
     breakdown: dict = field(default_factory=dict)
 
 
-def reduce(profile, *, chips: int, rounds: int) -> Reduction:
-    """Reduce a ``jax.profiler.ProfileData``."""
+def reduce(profile, *, chips: int, epochs: int, rounds: int) -> Reduction:
+    """Reduce a ``jax.profiler.ProfileData`` of a window that ran
+    ``epochs`` epochs of ``rounds`` train steps in all."""
     devices, host = {}, None
     for plane in profile.planes:
         m = DEVICE_PLANE.match(plane.name)
@@ -143,8 +160,10 @@ def reduce(profile, *, chips: int, rounds: int) -> Reduction:
     marks = [(a, b) for a, b, n, _ in host_events if n == WINDOW_MARK]
     if not marks:
         raise RuntimeError(f"no {WINDOW_MARK!r} annotation in the trace")
+    if len(marks) != epochs:
+        raise RuntimeError(f"the trace holds {len(marks)} {WINDOW_MARK!r} "
+                           f"marks; the window ran {epochs} epochs")
     lo, hi = min(a for a, _ in marks), max(b for _, b in marks)
-    starts = sorted(a for a, _ in marks)
 
     def inside(e):
         return e.start_ns < hi and e.start_ns + e.duration_ns > lo
@@ -158,19 +177,18 @@ def reduce(profile, *, chips: int, rounds: int) -> Reduction:
         if MODULE_LINE not in lines or OP_LINE not in lines:
             raise RuntimeError(f"TPU plane {i} lacks the {MODULE_LINE!r} "
                                f"or {OP_LINE!r} line")
-        stepped = set()             # epochs whose first step has run
+        steps = [0]                 # train steps of each epoch, in order
+        redrawn = False             # whether an epoch module ran
         for e in sorted((e for e in lines[MODULE_LINE].events if inside(e)),
                         key=lambda e: e.start_ns):
             name = module_name(e.name)
-            epoch = max(bisect.bisect_right(starts, e.start_ns) - 1, 0)
             if name == TRAIN_STEP:
-                stepped.add(epoch)
-            elif name in EPOCH_MODULES and epoch in stepped:
-                raise UnknownModule(
-                    f"epoch module {name!r} ran after its epoch's first "
-                    "train step: step work in a module trace_reduce "
-                    "takes for the epoch's re-initialisation")
-            elif name not in STEP_MODULES and name not in EPOCH_MODULES:
+                steps[-1] += 1
+            elif name in EPOCH_MODULES:
+                redrawn = True
+                if steps[-1]:
+                    steps.append(0)
+            elif name not in STEP_MODULES:
                 raise UnknownModule(
                     f"the window ran device module {name!r}, which "
                     "trace_reduce lists neither as a step module nor as an "
@@ -179,6 +197,7 @@ def reduce(profile, *, chips: int, rounds: int) -> Reduction:
                     - max(e.start_ns, lo)) * 1e-9
             modules[name] = modules.get(name, 0.0) + secs
             step += secs
+        _check_epochs(i, steps, redrawn, epochs, rounds)
         ops = [(max(e.start_ns, lo), min(e.start_ns + e.duration_ns, hi),
                 e.name) for e in lines[OP_LINE].events if inside(e)]
         for a, b, n in ops:
@@ -203,6 +222,22 @@ def reduce(profile, *, chips: int, rounds: int) -> Reduction:
                                   (b - a) * 1e-9] for a, b in idle]})
 
 
+def _check_epochs(chip: int, steps: list[int], redrawn: bool, epochs: int,
+                  rounds: int) -> None:
+    """Chip ``chip`` ran ``steps[k]`` train steps in its k-th epoch."""
+    if sum(steps) != rounds:
+        raise UnknownModule(
+            f"TPU {chip} ran {sum(steps)} train steps in the window, which "
+            f"ran {rounds}: a step under another name, or one left out")
+    if redrawn and steps != [rounds // epochs] * epochs:
+        raise UnknownModule(
+            f"TPU {chip} ran its epoch modules between train steps "
+            f"{steps}, where the window ran {epochs} epochs of "
+            f"{rounds // epochs}: an epoch module after its epoch's first "
+            "train step, step work in a module trace_reduce takes for the "
+            "epoch's re-initialisation")
+
+
 def _host_activity(host_events, a: float, b: float) -> str:
     """What the host was doing in the gap [a, b]: the host event, other
     than the epoch annotation, that overlaps the gap most (the shorter
@@ -217,7 +252,8 @@ def _host_activity(host_events, a: float, b: float) -> str:
     return best
 
 
-def reduce_dir(trace_dir: str, *, chips: int, rounds: int) -> Reduction:
+def reduce_dir(trace_dir: str, *, chips: int, epochs: int,
+               rounds: int) -> Reduction:
     """Reduce the one ``.xplane.pb`` under ``trace_dir``."""
     from jax.profiler import ProfileData
     paths = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
@@ -225,4 +261,4 @@ def reduce_dir(trace_dir: str, *, chips: int, rounds: int) -> Reduction:
         raise RuntimeError(f"expected one .xplane.pb under {trace_dir}, "
                            f"found {len(paths)}")
     return reduce(ProfileData.from_file(paths[0]), chips=chips,
-                  rounds=rounds)
+                  epochs=epochs, rounds=rounds)

@@ -111,6 +111,22 @@ def bytes_per_snapshot(s: Shape) -> dict[str, int]:
     return {"forward": fwd, "backward": bwd, "total": fwd + bwd}
 
 
+def spmm_per_snapshot(s: Shape) -> dict[str, int]:
+    """FLOPs and least HBM bytes of one snapshot's aggregation passes
+    alone (``A_tilde @ X``: every layer's forward and every layer's
+    transpose but the first's), by the rules above: a multiply and an add
+    per lane and feature; per pass the edge list and its weights read,
+    one source row gathered per lane at its own width, and the (N, d)
+    result written."""
+    n, e = s.num_nodes, s.lanes
+    passes = [d_in for layer, (d_in, _) in enumerate(s.widths())
+              for _ in range(2 if layer else 1)]
+    flops = sum(2 * e * d for d in passes)
+    nbytes = sum((2 * INDEX + F32) * e + F32 * d * e + F32 * n * d
+                 for d in passes)
+    return {"flops": flops, "bytes": nbytes}
+
+
 def alltoall_bytes_per_round(win: int, num_nodes: int, feat: int,
                              layers: int, chips: int) -> float:
     """Bytes crossing the chips' links in one round of ``win`` snapshots
