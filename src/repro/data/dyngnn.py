@@ -25,6 +25,16 @@ from repro.stream import encoder as stream_encoder
 from repro.stream import sharded as stream_sharded
 
 
+# host memory the pipeline may spend to keep the encoded trace (the
+# epinions shape at T=16 holds 310 MB; at its published T=512, 8 GB)
+HOST_STREAM_CACHE_BYTES = 1 << 30
+
+
+def _host_bytes(item) -> int:
+    return sum(v.nbytes for v in vars(item).values()
+               if isinstance(v, np.ndarray))
+
+
 @dataclass
 class DTDGDataset:
     snapshots: list[np.ndarray]
@@ -91,13 +101,24 @@ class DTDGPipeline:
         self.use_graph_diff = use_graph_diff
         self._batch = None
         # streamed transfer: vectorized encoder, churn-stat-sized pads.
-        # Only the byte total is retained — the streaming paths re-encode
-        # lazily (host_stream), so holding T padded items here would just
-        # duplicate the trace in host memory.
+        # Every epoch streams the same encoded trace, so its items are
+        # kept while they fit HOST_STREAM_CACHE_BYTES of host memory and
+        # an epoch only stages them; a larger trace is re-encoded lazily
+        # each epoch (host_stream) rather than held whole.
         self.stream_stats = stream_encoder.measure_stats(
             ds.snapshots, ds.num_nodes, self.bsize, max_edges)
-        self._stream_bytes = sum(
-            item.payload_bytes for item in self.host_stream())
+        report = stream_encoder.StreamReport()
+        items: list | None = []
+        held = self._stream_bytes = 0
+        for item in self._encode(report):
+            self._stream_bytes += item.payload_bytes
+            held += _host_bytes(item)
+            if items is not None and held <= HOST_STREAM_CACHE_BYTES:
+                items.append(item)
+            else:
+                items = None
+        # a resync is counted on the report of the epoch that meets it
+        self.stream_items = items if report.resyncs == 0 else None
 
     @property
     def batch(self):
@@ -120,11 +141,17 @@ class DTDGPipeline:
         return {"graph_diff": gd, "naive": base,
                 "ratio": gd / max(base, 1)}
 
-    def host_stream(self):
-        """Lazy re-encode of the trace (what the prefetch thread drains)."""
+    def _encode(self, report=None):
         return stream_encoder.iter_encode_stream(
             self.ds.snapshots, self.ds.values, self.ds.num_nodes,
-            self.max_edges, self.bsize, self.stream_stats)
+            self.max_edges, self.bsize, self.stream_stats, report=report)
+
+    def host_stream(self):
+        """The encoded trace (what the prefetch thread drains): the kept
+        items, else a lazy re-encode."""
+        if self.stream_items is not None:
+            return iter(self.stream_items)
+        return self._encode()
 
     def sharded_streams(self, num_shards: int, wire: str = "none"):
         """Per-shard time-slice streams for snapshot partitioning

@@ -147,7 +147,8 @@ def fit_streamed(rr: ResolvedRun) -> RunResult:
         num_epochs=plan.num_epochs, overlap=plan.overlap,
         prefetch_depth=plan.prefetch_depth, opt_cfg=opt_cfg,
         params=params, opt_state=opt_state, stats=pipe.stream_stats,
-        max_edges=pipe.max_edges, report=report, step_fn=step_fn,
+        max_edges=pipe.max_edges, report=report,
+        encoded=pipe.stream_items, step_fn=step_fn,
         log_every=rr.log_every, log_fn=rr.log_fn)
     state = trainer.TrainState(params=st.params, opt_state=st.opt_state,
                                step=len(st.losses))

@@ -166,10 +166,13 @@ def make_stream_slice_step(cfg: mdl.DynGNNConfig,
 def host_stream(snapshots, values, frames, labels, num_nodes: int,
                 max_edges: int, block_size: int,
                 stats: enc.DeltaStats | None = None,
-                report: enc.StreamReport | None = None):
-    """Host iterator of (delta item, frame_t, labels_t) per step."""
-    it = enc.iter_encode_stream(snapshots, values, num_nodes, max_edges,
-                                block_size, stats, report=report)
+                report: enc.StreamReport | None = None,
+                encoded=None):
+    """Host iterator of (delta item, frame_t, labels_t) per step;
+    ``encoded`` replays already encoded items instead of encoding."""
+    it = (iter(encoded) if encoded is not None else
+          enc.iter_encode_stream(snapshots, values, num_nodes, max_edges,
+                                 block_size, stats, report=report))
     for t, item in enumerate(it):
         yield (item, np.asarray(frames[t]), np.asarray(labels[t]))
 
@@ -204,6 +207,7 @@ def train_streamed(cfg: mdl.DynGNNConfig, snapshots, values, frames,
                    max_edges: int | None = None,
                    slice_len: int | None = None,
                    report: enc.StreamReport | None = None,
+                   encoded=None,
                    step_fn=None,
                    seed: int = 0,
                    log_every: int = 10,
@@ -220,6 +224,10 @@ def train_streamed(cfg: mdl.DynGNNConfig, snapshots, values, frames,
     reference semantics of the distributed streamed trainer, which shards
     exactly this slice over its mesh).  ``slice_len`` in (None, 1) keeps
     the per-snapshot schedule unchanged.
+
+    ``encoded`` is the trace as ``enc.iter_encode_stream`` gives it for
+    these (stats, max_edges, block_size); each epoch then stages those
+    items instead of encoding the trace anew.
 
     ``step_fn`` lets callers that invoke this in a loop (the Engine's
     streamed worker, benchmark epochs) reuse one compiled step instead of
@@ -246,7 +254,8 @@ def train_streamed(cfg: mdl.DynGNNConfig, snapshots, values, frames,
         step_fn = (make_stream_slice_step(cfg, opt_cfg) if sliced
                    else make_stream_train_step(cfg, opt_cfg))
     mk_host = partial(host_stream, snapshots, values, frames, labels,
-                      cfg.num_nodes, max_edges, block_size, stats, report)
+                      cfg.num_nodes, max_edges, block_size, stats, report,
+                      encoded)
     if sliced and t_steps % slice_len:
         raise ValueError(f"slice_len {slice_len} must divide the trace "
                          f"length {t_steps}")

@@ -96,6 +96,36 @@ def test_streamed_matches_train_streamed(model):
     assert got.transfer_report["graph_diff"] > 0
 
 
+def test_streamed_replays_the_kept_trace(monkeypatch):
+    """The pipeline keeps the encoded trace within its host budget; every
+    epoch of every fit stages those items, which nothing writes to (the
+    delta-apply donates the device buffers they were staged into), and
+    trains as a pipeline that re-encodes each epoch does."""
+    from repro.data import dyngnn
+    from repro.stream import encoder as stream_encoder
+    cfg = _cfg("cdgcn", t=8)
+    ds = _src("cdgcn", t=8).build()
+    pipe = DTDGPipeline(ds, nb=cfg.checkpoint_blocks)
+    assert pipe.stream_items is not None
+    assert len(pipe.stream_items) == ds.num_steps
+    eng = _engine(cfg, InMemoryDTDG(ds, pipeline=pipe),
+                  ExecutionPlan(mode="streamed"))
+    first, second = eng.fit().losses, eng.fit().losses
+    fresh = stream_encoder.iter_encode_stream(
+        ds.snapshots, ds.values, ds.num_nodes, pipe.max_edges, pipe.bsize,
+        pipe.stream_stats)
+    for kept, new in zip(pipe.stream_items, fresh, strict=True):
+        for k, v in vars(new).items():
+            np.testing.assert_array_equal(getattr(kept, k), v)
+    monkeypatch.setattr(dyngnn, "HOST_STREAM_CACHE_BYTES", 0)
+    lazy = DTDGPipeline(ds, nb=cfg.checkpoint_blocks)
+    assert lazy.stream_items is None
+    assert lazy.transfer_bytes() == pipe.transfer_bytes()
+    want = _engine(cfg, InMemoryDTDG(ds, pipeline=lazy),
+                   ExecutionPlan(mode="streamed")).fit().losses
+    assert first == second == want
+
+
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 host devices")
 def test_streamed_mesh_matches_distributed_loop():
     """Engine streamed_mesh == train_distributed_streamed on the same
